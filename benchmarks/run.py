@@ -13,6 +13,8 @@ import argparse
 import sys
 import time
 
+from repro import compile_cache
+
 from benchmarks import (bench_ap_backend, bench_cycles, bench_faults,
                         bench_policy, bench_roofline, bench_serving,
                         bench_speedup_power, bench_stack, bench_sweep,
@@ -51,6 +53,7 @@ def main(argv=None) -> None:
     ap.add_argument("sections", nargs="*", choices=[[]] + list(SECTIONS),
                     help="sections to run (default: all)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     wanted = args.sections or list(SECTIONS)
     section_argv = ["--quick"] if args.quick else []
     for name in wanted:

@@ -254,7 +254,9 @@ def coarse_factorization(levels: list):
     the identity, symmetrically Jacobi-scale it for float32 conditioning,
     pin void rows to identity, and Cholesky-factor ONCE per hierarchy;
     every cycle then solves the coarsest level exactly (a symmetric
-    operation, so the preconditioner property is preserved).
+    operation, so the preconditioner property is preserved).  The factor
+    and every solve with it run at ``HIGHEST`` matmul precision: the
+    near-null mode is exactly what bf16 MXU operands would blur.
     """
     F, d_extra = levels[-1]
     L, ny, nx = F["g_pkg"].shape
@@ -268,7 +270,8 @@ def coarse_factorization(levels: list):
     A = A + jnp.diag(jnp.where(void, 1.0, 0.0))     # void cells: u = 0
     s = 1.0 / jnp.sqrt(jnp.where(void, 1.0, d))     # Jacobi scaling
     As = s[:, None] * A * s[None, :]
-    return jax.scipy.linalg.cho_factor(As), s
+    with jax.default_matmul_precision("highest"):
+        return jax.scipy.linalg.cho_factor(As), s
 
 
 def coarse_solve_fn(levels: list):
@@ -278,7 +281,8 @@ def coarse_solve_fn(levels: list):
     shape = levels[-1][0]["g_pkg"].shape
 
     def solve(b):
-        y = jax.scipy.linalg.cho_solve(cf, s * b.ravel())
+        with jax.default_matmul_precision("highest"):
+            y = jax.scipy.linalg.cho_solve(cf, s * b.ravel())
         return (s * y).reshape(shape)
 
     return solve

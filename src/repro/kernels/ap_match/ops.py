@@ -16,7 +16,7 @@ from repro.kernels.ap_match import ref as _ref
 
 def run_schedule(planes: jax.Array, cmp_cols, cmp_key, w_cols, w_key, *,
                  backend: str = "pallas", block_lanes: int = 512,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """Execute a full AP pass schedule.
 
     planes : uint32[n_bits, n_lanes]

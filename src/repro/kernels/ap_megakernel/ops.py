@@ -50,19 +50,17 @@ def _sharded_runner(mesh):
     ``group_scan`` makes every shard compute identical counts — integer
     addition is exact in any order, hence device-count invariance).
     """
-    from jax.experimental.shard_map import shard_map
-
     def body(planes, tag, op, cond, enabled, cc, ck, wc, wk):
         return ref.group_scan(planes, tag, (op, cond, cc, ck, wc, wk),
                               enabled, axis_name="lanes")
 
     rep = P()
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, "lanes"), P("lanes"), rep, rep, rep, rep, rep,
                   rep, rep),
         out_specs=(P(None, "lanes"), P("lanes"), rep, rep),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def run(planes, tag, op, cond, enabled, cc, ck, wc, wk):
@@ -74,7 +72,7 @@ def _sharded_runner(mesh):
 
 def run_group(planes, tag, group: OpGroup, enabled=None, *,
               backend: str = "jnp", mesh=None, block_lanes: int = 512,
-              interpret: bool = True):
+              interpret: bool | None = None):
     """Execute one op group -> (planes', tag', matched int32[P]).
 
     enabled : optional bool[P] dynamic op mask (default: all on)

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels._platform import interpret_mode, row_block
+
 
 def _rb_line_kernel(t_ref, up_ref, dn_ref, b_ref, gxl_ref, gxr_ref,
                     gyu_ref, gyd_ref, gzu_ref, gzd_ref, gp_ref, de_ref,
@@ -76,11 +78,9 @@ def _rb_line_kernel(t_ref, up_ref, dn_ref, b_ref, gxl_ref, gxr_ref,
 def rb_line_sweep_kernel(T: jax.Array, b: jax.Array, gx_lf, gx_rt, gy_up,
                          gy_dn, gz_up, gz_dn, g_pkg, d_extra, *,
                          color: int, block_y: int = 32,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     L, ny, nx = T.shape
-    by = min(block_y, ny)
-    while ny % by != 0:          # largest divisor <= requested block
-        by -= 1
+    by = row_block(ny, block_y)
     n_blocks = ny // by
 
     kern = functools.partial(_rb_line_kernel, color=color, block_y=by,
@@ -96,5 +96,6 @@ def rb_line_sweep_kernel(T: jax.Array, b: jax.Array, gx_lf, gx_rt, gy_up,
         in_specs=[tile, spec_up, spec_dn] + [tile] * 9,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((L, ny, nx), T.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="mg_smooth",
     )(T, T, T, b, gx_lf, gx_rt, gy_up, gy_dn, gz_up, gz_dn, g_pkg, d_extra)

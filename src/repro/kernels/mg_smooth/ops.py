@@ -19,7 +19,7 @@ from repro.kernels.mg_smooth import kernel as _kernel
                                              "interpret"))
 def rb_line_sweep(T: jax.Array, b: jax.Array, F: dict, d_extra,
                   color: int, *, block_y: int = 32,
-                  interpret: bool = True) -> jax.Array:
+                  interpret: bool | None = None) -> jax.Array:
     """One red-black z-line Gauss-Seidel half-sweep (Pallas path)."""
     import jax.numpy as jnp
     d_extra = jnp.broadcast_to(jnp.asarray(d_extra, T.dtype), T.shape)

@@ -16,8 +16,11 @@ TPU adaptation: the grid tiles the **y axis**; each program holds an
 vertical and x couplings never leave VMEM.  The y halo comes from passing
 the SAME array with index maps i-1 / i+1 (clamped); boundary programs
 replicate their own edge row (adiabatic = zero difference), matching the
-reference operator exactly.  VPU-only elementwise work — memory-bound by
-design (arithmetic intensity ~1 flop/byte).
+reference operator exactly.  BLOCK_Y is 8-aligned or the whole axis
+(:func:`repro.kernels._platform.row_block`); the per-layer conductance
+vectors of the uniform operator ride in SMEM and are read as scalars.
+VPU-only elementwise work — memory-bound by design (arithmetic
+intensity ~1 flop/byte).
 """
 from __future__ import annotations
 
@@ -26,17 +29,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels._platform import interpret_mode, row_block
 
 
-def _stencil_kernel(gl_ref, gu_ref, gd_ref, gp_ref, c_ref, up_ref, dn_ref,
-                    y_ref, *, n_blocks: int):
+def _neighbors(C, up_ref, dn_ref, n_blocks: int):
+    """The six neighbor planes of a tile (edge-replicated at every
+    global boundary; the y halo rows come from the adjacent tiles)."""
     i = pl.program_id(0)
-    C = c_ref[...]                                   # [L, BY, nx]
-    gl = gl_ref[...][:, None, None]
-    gu = gu_ref[...][:, None, None]
-    gd = gd_ref[...][:, None, None]
-    gp = gp_ref[...][:, None, None]
-    # y-halo rows: neighbor tile edge, or own edge at the global boundary
     above = jnp.where(i > 0, up_ref[:, -1:, :], C[:, :1, :])
     below = jnp.where(i < n_blocks - 1, dn_ref[:, :1, :], C[:, -1:, :])
     t_up = jnp.concatenate([above, C[:, :-1, :]], axis=1)
@@ -45,9 +46,19 @@ def _stencil_kernel(gl_ref, gu_ref, gd_ref, gp_ref, c_ref, up_ref, dn_ref,
     t_rt = jnp.concatenate([C[:, :, 1:], C[:, :, -1:]], axis=2)
     l_up = jnp.concatenate([C[:1], C[:-1]], axis=0)
     l_dn = jnp.concatenate([C[1:], C[-1:]], axis=0)
+    return t_up, t_dn, t_lf, t_rt, l_up, l_dn
 
-    y_ref[...] = gl * (4.0 * C - t_up - t_dn - t_lf - t_rt) \
-        + gu * (C - l_up) + gd * (C - l_dn) + gp * C
+
+def _stencil_kernel(gl_ref, gu_ref, gd_ref, gp_ref, c_ref, up_ref, dn_ref,
+                    y_ref, *, n_blocks: int):
+    C = c_ref[...]                                   # [L, BY, nx]
+    t_up, t_dn, t_lf, t_rt, l_up, l_dn = _neighbors(C, up_ref, dn_ref,
+                                                    n_blocks)
+    for l in range(C.shape[0]):                      # per-layer SMEM scalars
+        y_ref[l] = gl_ref[l] * (4.0 * C[l] - t_up[l] - t_dn[l] - t_lf[l]
+                                - t_rt[l]) \
+            + gu_ref[l] * (C[l] - l_up[l]) + gd_ref[l] * (C[l] - l_dn[l]) \
+            + gp_ref[l] * C[l]
 
 
 def _field_kernel(c_ref, up_ref, dn_ref, gxl_ref, gxr_ref, gyu_ref, gyd_ref,
@@ -55,71 +66,60 @@ def _field_kernel(c_ref, up_ref, dn_ref, gxl_ref, gxr_ref, gyu_ref, gyd_ref,
     """Heterogeneous per-face conductances (zero face = adiabatic):
     the production operator — silicon exists only over the die footprint,
     the spreader spans the full (die + margin) domain."""
-    i = pl.program_id(0)
     C = c_ref[...]
-    above = jnp.where(i > 0, up_ref[:, -1:, :], C[:, :1, :])
-    below = jnp.where(i < n_blocks - 1, dn_ref[:, :1, :], C[:, -1:, :])
-    t_up = jnp.concatenate([above, C[:, :-1, :]], axis=1)
-    t_dn = jnp.concatenate([C[:, 1:, :], below], axis=1)
-    t_lf = jnp.concatenate([C[:, :, :1], C[:, :, :-1]], axis=2)
-    t_rt = jnp.concatenate([C[:, :, 1:], C[:, :, -1:]], axis=2)
-    l_up = jnp.concatenate([C[:1], C[:-1]], axis=0)
-    l_dn = jnp.concatenate([C[1:], C[-1:]], axis=0)
+    t_up, t_dn, t_lf, t_rt, l_up, l_dn = _neighbors(C, up_ref, dn_ref,
+                                                    n_blocks)
     y_ref[...] = gxl_ref[...] * (C - t_lf) + gxr_ref[...] * (C - t_rt) \
         + gyu_ref[...] * (C - t_up) + gyd_ref[...] * (C - t_dn) \
         + gzu_ref[...] * (C - l_up) + gzd_ref[...] * (C - l_dn) \
         + gp_ref[...] * C
 
 
-@functools.partial(jax.jit, static_argnames=("block_y", "interpret"))
-def apply_operator_fields_kernel(T: jax.Array, gx_lf, gx_rt, gy_up, gy_dn,
-                                 gz_up, gz_dn, g_pkg, *, block_y: int = 32,
-                                 interpret: bool = True) -> jax.Array:
-    L, ny, nx = T.shape
-    by = min(block_y, ny)
-    while ny % by != 0:
-        by -= 1
-    n_blocks = ny // by
-
-    kern = functools.partial(_field_kernel, n_blocks=n_blocks)
+def _tile_specs(L: int, by: int, nx: int, n_blocks: int):
+    """(own tile, tile above, tile below) BlockSpecs over the y axis."""
     tile = pl.BlockSpec((L, by, nx), lambda i: (0, i, 0))
     spec_up = pl.BlockSpec((L, by, nx),
                            lambda i: (0, jnp.maximum(i - 1, 0), 0))
     spec_dn = pl.BlockSpec((L, by, nx),
                            lambda i: (0, jnp.minimum(i + 1, n_blocks - 1), 0))
+    return tile, spec_up, spec_dn
+
+
+@functools.partial(jax.jit, static_argnames=("block_y", "interpret"))
+def apply_operator_fields_kernel(T: jax.Array, gx_lf, gx_rt, gy_up, gy_dn,
+                                 gz_up, gz_dn, g_pkg, *, block_y: int = 32,
+                                 interpret: bool | None = None) -> jax.Array:
+    L, ny, nx = T.shape
+    by = row_block(ny, block_y)
+    n_blocks = ny // by
+    tile, spec_up, spec_dn = _tile_specs(L, by, nx, n_blocks)
     return pl.pallas_call(
-        kern,
+        functools.partial(_field_kernel, n_blocks=n_blocks),
         grid=(n_blocks,),
         in_specs=[tile, spec_up, spec_dn] + [tile] * 7,
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((L, ny, nx), T.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="thermal_stencil_fields",
     )(T, T, T, gx_lf, gx_rt, gy_up, gy_dn, gz_up, gz_dn, g_pkg)
 
 
 @functools.partial(jax.jit, static_argnames=("block_y", "interpret"))
 def apply_operator_kernel(T: jax.Array, g_lat: jax.Array, gv_up: jax.Array,
                           gv_dn: jax.Array, g_pkg_vec: jax.Array, *,
-                          block_y: int = 32, interpret: bool = True
+                          block_y: int = 32, interpret: bool | None = None
                           ) -> jax.Array:
     L, ny, nx = T.shape
-    by = min(block_y, ny)
-    while ny % by != 0:          # largest divisor <= requested block
-        by -= 1
+    by = row_block(ny, block_y)
     n_blocks = ny // by
-
-    kern = functools.partial(_stencil_kernel, n_blocks=n_blocks)
-    vec = pl.BlockSpec((L,), lambda i: (0,))
-    spec_c = pl.BlockSpec((L, by, nx), lambda i: (0, i, 0))
-    spec_up = pl.BlockSpec((L, by, nx),
-                           lambda i: (0, jnp.maximum(i - 1, 0), 0))
-    spec_dn = pl.BlockSpec((L, by, nx),
-                           lambda i: (0, jnp.minimum(i + 1, n_blocks - 1), 0))
+    tile, spec_up, spec_dn = _tile_specs(L, by, nx, n_blocks)
+    vec = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        kern,
+        functools.partial(_stencil_kernel, n_blocks=n_blocks),
         grid=(n_blocks,),
-        in_specs=[vec, vec, vec, vec, spec_c, spec_up, spec_dn],
-        out_specs=pl.BlockSpec((L, by, nx), lambda i: (0, i, 0)),
+        in_specs=[vec, vec, vec, vec, tile, spec_up, spec_dn],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((L, ny, nx), T.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
+        name="thermal_stencil",
     )(g_lat, gv_up, gv_dn, g_pkg_vec, T, T, T)

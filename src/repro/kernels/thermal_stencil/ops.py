@@ -16,7 +16,8 @@ from repro.kernels.thermal_stencil import kernel as _kernel
 
 
 def apply_operator(T: jax.Array, g_lat, g_vert, g_pkg, *,
-                   block_y: int = 32, interpret: bool = True) -> jax.Array:
+                   block_y: int = 32,
+                   interpret: bool | None = None) -> jax.Array:
     """y = G @ T (same contract as core.thermal.apply_operator)."""
     L = T.shape[0]
     g_lat, gv_u, gv_d, g_pkg_vec = _vectors(L, g_lat, g_vert, g_pkg)
@@ -26,7 +27,7 @@ def apply_operator(T: jax.Array, g_lat, g_vert, g_pkg, *,
 
 
 def apply_operator_fields(T: jax.Array, F: dict, *, block_y: int = 32,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """Heterogeneous operator (same contract as
     core.thermal.apply_operator_fields)."""
     return _kernel.apply_operator_fields_kernel(
@@ -38,7 +39,7 @@ def apply_operator_fields(T: jax.Array, F: dict, *, block_y: int = 32,
                                              "interpret"))
 def cg_solve_fields_stats(b: jax.Array, F: dict, tol: float = 1e-8,
                           max_iter: int = 8000, block_y: int = 32,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Jacobi-preconditioned CG on the heterogeneous Pallas stencil.
 
     Returns ``(x, n_iterations)`` like :func:`repro.core.thermal.pcg`.
@@ -51,7 +52,7 @@ def cg_solve_fields_stats(b: jax.Array, F: dict, tol: float = 1e-8,
 
 def cg_solve_fields(b: jax.Array, F: dict, tol: float = 1e-8,
                     max_iter: int = 8000, block_y: int = 32,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     return cg_solve_fields_stats(b, F, tol, max_iter, block_y,
                                  interpret)[0]
 
@@ -60,7 +61,7 @@ def cg_solve_fields(b: jax.Array, F: dict, tol: float = 1e-8,
                                              "interpret"))
 def cg_solve(b: jax.Array, diag: jax.Array, g_lat, g_vert, g_pkg,
              tol: float = 1e-8, max_iter: int = 6000,
-             block_y: int = 32, interpret: bool = True) -> jax.Array:
+             block_y: int = 32, interpret: bool | None = None) -> jax.Array:
     """Jacobi-preconditioned CG for G T = b with the Pallas stencil."""
     from repro.core.thermal import pcg
     L = b.shape[0]

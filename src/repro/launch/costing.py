@@ -46,8 +46,6 @@ from repro.optim import AdamWConfig, adamw_init, adamw_update
 def _cost_of(jitted, args) -> dict:
     compiled = jitted.lower(*args).compile()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jaxlib: one dict per program
-        cost = cost[0] if cost else {}
     coll = RF.parse_collectives(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "bytes": float(cost.get("bytes accessed", 0.0)),

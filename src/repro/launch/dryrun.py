@@ -61,8 +61,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jaxlib: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = RF.parse_collectives(hlo)
 

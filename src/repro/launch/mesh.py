@@ -15,12 +15,20 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the model code places values
+    with ``with_sharding_constraint``, which only Auto axes accept
+    (``make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

@@ -135,10 +135,19 @@ def to_named(mesh, spec_tree: Any) -> Any:
 # A sweep batch is embarrassingly parallel over its leading (case) axis:
 # every case is an independent closed-loop replay.  ``shard_case_batch``
 # wraps the vmapped replay in a ``shard_map`` over a 1D 'cases' mesh, so
-# each device runs the identical per-case program on its slice — results
-# are bitwise what the unsharded vmap produces, which is what keeps the
-# content-hashed sweep cache device-count-invariant
-# (tests/test_shard_sweep.py pins 1 shard vs N shards bit-equal).
+# each device runs the per-case program on its slice.  The per-device
+# batch is smaller than the unsharded one, and XLA does not promise the
+# same reduction order for different batch shapes: on the CPU a batch
+# of one turns each batched CG inner product into a plain dot with a
+# different summation order.  So a sharded replay agrees with the
+# unsharded vmap within SHARD_TOL_C (tests/test_shard_sweep.py), not bit
+# for bit, and the sweep cache keys the shard count
+# (``repro.sweep.cache.path_for``).
+
+#: stated agreement [°C] of a sharded sweep replay with the unsharded
+#: one, on temperatures and Picard residuals: the replay's own
+#: per-interval convergence bar (``FeedbackParams.picard_tol_C``)
+SHARD_TOL_C = 0.05
 
 def sweep_mesh(n_shards: int | None = None):
     """A 1D mesh of ``n_shards`` local devices over axis 'cases'.
@@ -208,7 +217,6 @@ def shard_case_batch(fn, mesh):
     first, and return a pytree of case-major outputs; the leading axis
     must already be a multiple of the mesh size (:func:`pad_case_batch`).
     """
-    from jax.experimental.shard_map import shard_map
     spec = P("cases")
-    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)

@@ -561,10 +561,10 @@ def closed_loop_sharded(dyn_frames, leak0, refresh0, logic_mask, F: dict,
     The case batch is padded to a multiple of the mesh size (repeating
     the last case; padding rows are dropped from every output) and run
     through ``shard_map`` over a 1D 'cases' mesh
-    (``repro.parallel.sharding``).  Each device executes the identical
-    per-case program on its slice, so results are bitwise those of the
-    unsharded vmap for ANY device count — the property the sweep cache
-    relies on (tests/test_shard_sweep.py).
+    (``repro.parallel.sharding``).  Each device executes the per-case
+    program on its slice.  Results agree with the unsharded vmap within
+    ``sharding.SHARD_TOL_C``: a smaller per-device batch may reorder
+    XLA's reductions (tests/test_shard_sweep.py).
     """
     from repro.parallel import sharding as shardlib
     mesh = shardlib.sweep_mesh(n_shards)

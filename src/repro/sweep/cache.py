@@ -1,9 +1,12 @@
 """Content-hashed on-disk result cache for sweeps.
 
-One sweep = one ``sweep_<hash>.npz`` under the cache directory
+One sweep = one ``sweep_<hash>[_s<N>].npz`` under the cache directory
 (``$REPRO_SWEEP_CACHE`` or ``.sweep_cache/``), where ``<hash>`` is
 :meth:`SweepSpec.content_hash` — a SHA-256 digest of the spec's
-canonical JSON plus a schema version (DESIGN.md §8).  The npz holds the
+canonical JSON plus a schema version (DESIGN.md §8) — and ``_s<N>``
+names the shard count of a sharded replay (DESIGN.md §7.6: sharded
+results agree with the unsharded ones to a stated tolerance, not bit
+for bit, so they are cached apart).  The npz holds the
 per-record result arrays verbatim (float32/float64, so reloads are
 bit-identical) and a JSON manifest with the full canonical spec, which
 :func:`load` verifies against the requesting spec so a truncated-hash
@@ -46,14 +49,16 @@ def default_cache_dir() -> Path:
     return Path(os.environ.get("REPRO_SWEEP_CACHE", ".sweep_cache"))
 
 
-def path_for(spec: SweepSpec, cache_dir=None) -> Path:
+def path_for(spec: SweepSpec, cache_dir=None, n_shards=None) -> Path:
     base = Path(cache_dir) if cache_dir is not None else default_cache_dir()
-    return base / f"sweep_{spec.content_hash()}.npz"
+    shards = f"_s{n_shards}" if n_shards else ""
+    return base / f"sweep_{spec.content_hash()}{shards}.npz"
 
 
-def store(result: SweepResult, cache_dir=None) -> Path:
-    """Persist a sweep result; returns the written path."""
-    path = path_for(result.spec, cache_dir)
+def store(result: SweepResult, cache_dir=None, n_shards=None) -> Path:
+    """Persist a sweep result replayed over ``n_shards`` devices
+    (None/0 = the plain vmap); returns the written path."""
+    path = path_for(result.spec, cache_dir, n_shards)
     path.parent.mkdir(parents=True, exist_ok=True)
     payload: dict[str, np.ndarray] = {}
     for i, rec in enumerate(result.records):
@@ -76,11 +81,13 @@ def store(result: SweepResult, cache_dir=None) -> Path:
     return path
 
 
-def load(spec: SweepSpec, cache_dir=None) -> SweepResult | None:
-    """Load a cached sweep for ``spec``; None on miss, manifest mismatch
-    (hash-collision guard), or a corrupt/truncated file (recompute and
-    overwrite rather than fail the sweep)."""
-    path = path_for(spec, cache_dir)
+def load(spec: SweepSpec, cache_dir=None, n_shards=None
+         ) -> SweepResult | None:
+    """Load a cached sweep for ``spec`` replayed over ``n_shards``
+    devices; None on miss, manifest mismatch (hash-collision guard), or
+    a corrupt/truncated file (recompute and overwrite rather than fail
+    the sweep)."""
+    path = path_for(spec, cache_dir, n_shards)
     if not path.exists():
         obs.count("sweep/cache/miss")
         return None

@@ -214,15 +214,16 @@ def run_sweep(spec: SweepSpec, cache_dir=None, use_cache: bool = True,
 
     ``n_shards`` partitions every group's case batch over that many
     local devices (``shard_map`` over a 'cases' mesh; None/0 = plain
-    single-device vmap).  It is an EXECUTION knob, not part of the
-    spec: per-case results are bitwise identical for any shard count,
-    so cache keys and cached artifacts do not depend on it.
+    single-device vmap).  It is an execution knob, not part of the
+    spec, but it is part of the cache key: a sharded replay agrees
+    with the unsharded one within ``sharding.SHARD_TOL_C``, not bit for
+    bit (DESIGN.md §7.6).
     """
     from repro.sweep import cache
     if params != PAPER_STACK:
         use_cache = False       # cache keys don't cover custom stack params
     if use_cache:
-        hit = cache.load(spec, cache_dir)
+        hit = cache.load(spec, cache_dir, n_shards)
         if hit is not None:
             return hit
 
@@ -258,7 +259,7 @@ def run_sweep(spec: SweepSpec, cache_dir=None, use_cache: bool = True,
     # never persist failures: a cached FAILED row would keep serving
     # the placeholder after the underlying cause is fixed
     if use_cache and not out.n_failed:
-        cache.store(out, cache_dir)
+        cache.store(out, cache_dir, n_shards)
     return out
 
 

@@ -27,7 +27,9 @@ import json
 #    2048 -> 2^20; traces at sizes past 2048^2 change element counts.
 # 5: policy axis (DTM/DVFS policy engine) and the dyn_W energy array in
 #    every record; pre-policy entries lack both.
-CACHE_SCHEMA = 5
+# 6: the shard count joins the cache key (sharded replays match the
+#    unsharded ones within a tolerance, not bitwise).
+CACHE_SCHEMA = 6
 
 #: trace-capture execution paths for the AP workloads (all bit-exact;
 #: the field exists so a spec records how its traces were captured)
@@ -79,8 +81,9 @@ class SweepSpec:
     # keeps its own fixed count (FeedbackParams.disabled)
     solver: str = "pcg"   # inner solve per implicit step (SOLVERS);
     # results depend on it (different fixed-cost approximations), so it
-    # is part of the spec and the cache key — unlike the shard count,
-    # which is a pure execution detail and deliberately NOT a field
+    # is part of the spec and the cache key.  The shard count is an
+    # execution knob and not a field; the cache keys it separately
+    # (``cache.path_for``)
     n_mg: int = 3         # V-cycles per step when solver == "mg"
     ap_backend: str = "device"   # AP trace-capture path (AP_BACKENDS);
     # every path is pinned bit-identical by the differential tests, so

@@ -343,7 +343,6 @@ def _mk_rounds_program(state, op, cond, cc, ck, wc, wk, remaining, *,
 def _mk_rounds_sharded(mesh, rounds, readout):
     """jit(shard_map(...)) of the rounds program over the 'lanes' axis,
     cached per (mesh, shape) so re-runs reuse the compiled program."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     st_spec = E.APState(P(None, "lanes"), P("lanes"), P())
@@ -353,11 +352,11 @@ def _mk_rounds_sharded(mesh, rounds, readout):
         return _mk_rounds_impl(state, op, cond, cc, ck, wc, wk, remaining,
                                rounds, readout, axis_name="lanes")
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(st_spec, rep, rep, rep, rep, rep, rep, rep),
         out_specs=(st_spec, (rep, P(None, "lanes"), rep)),
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def run(state, op, cond, cc, ck, wc, wk, remaining):

