@@ -1,0 +1,10 @@
+"""Run with `pytest bench/tests` from the checkout root, on the CPU."""
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (ROOT, ROOT / "src"):
+    if str(p) not in sys.path:
+        sys.path.insert(0, str(p))
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
