@@ -368,14 +368,20 @@ class APEngine:
         vals = np.asarray(values, np.uint64)
         if vals.shape != (self.n_words,):
             raise ValueError(f"expected ({self.n_words},), got {vals.shape}")
-        sub = bp.pack_words(vals, field.width)
-        self.planes = bp.set_field_planes(self.planes, sub, field.start)
+        with obs.span("engine/load", width=field.width):
+            with obs.span("engine/pack"):
+                sub = bp.pack_words(vals, field.width)
+            self.planes = bp.set_field_planes(self.planes, sub, field.start)
 
     def read(self, field: Field, signed: bool = False) -> np.ndarray:
         """Host-side readback of a field for all words (charges n read cycles)."""
-        self.charge_read(self.n_words)
-        sub = self.planes[field.start:field.start + field.width]
-        vals = np.asarray(bp.unpack_words(sub))
+        with obs.span("engine/read", width=field.width):
+            self.charge_read(self.n_words)
+            sub = self.planes[field.start:field.start + field.width]
+            with obs.span("sync/read"):
+                sub = np.asarray(sub)
+            with obs.span("engine/unpack"):
+                vals = bp.unpack_words(sub)
         if signed and field.width < 64:
             sign = vals >> (field.width - 1)
             vals = vals.astype(np.int64) - (sign.astype(np.int64) << field.width)
@@ -424,9 +430,10 @@ class APEngine:
 
     def bwrite(self, cols: Sequence[int], key: Sequence[int]) -> None:
         """Broadcast write (all rows): one cycle."""
-        self.planes = _broadcast_write_jit(
-            self.planes, jnp.asarray(cols, jnp.int32),
-            jnp.asarray(key, jnp.uint32))
+        with obs.span("engine/bwrite", width=len(cols)):
+            self.planes = _broadcast_write_jit(
+                self.planes, jnp.asarray(cols, jnp.int32),
+                jnp.asarray(key, jnp.uint32))
         self.cycles += 1
         self.bwrite_cycles += 1
         if self.collect_stats:
@@ -459,27 +466,31 @@ class APEngine:
 
     def charge_run(self, sched: PassSchedule, matched) -> None:
         """Account a full pass schedule from its per-pass matched counts."""
-        P = sched.n_passes
-        self.cycles += 2 * P           # each pass = compare + write
-        self.compare_cycles += P
-        self.write_cycles += P
-        if self.collect_stats:
-            m = np.asarray(matched, np.int64)
-            n = self.n_words
-            kc = sched.kc.astype(np.float64)
-            kw = sched.kw.astype(np.float64)
-            mf = m.astype(np.float64)
-            pw = self.power
-            e_pass = kc * (pw.p_m * mf + pw.p_mm * (n - mf)) \
-                + kw * (pw.p_w * mf + pw.p_mw * (n - mf))
-            self.energy += float(e_pass.sum())
-            self._trace_cycles.append(
-                self.cycles - 2 * P + 2 * np.arange(1, P + 1, dtype=np.int64))
-            self._trace_energy.append(e_pass)
-            self.events["match"] += int(m.sum())
-            self.events["mismatch"] += int(P) * n - int(m.sum())
-            self.events["write"] += int((kw * mf).sum())
-            self.events["miswrite"] += int((kw * (n - mf)).sum())
+        with obs.span("engine/charge", passes=sched.n_passes):
+            P = sched.n_passes
+            self.cycles += 2 * P           # each pass = compare + write
+            self.compare_cycles += P
+            self.write_cycles += P
+            if self.collect_stats:
+                if isinstance(matched, jax.Array):
+                    with obs.span("sync/matched"):
+                        matched = np.asarray(matched)
+                m = np.asarray(matched, np.int64)
+                n = self.n_words
+                kc = sched.kc.astype(np.float64)
+                kw = sched.kw.astype(np.float64)
+                mf = m.astype(np.float64)
+                pw = self.power
+                e_pass = kc * (pw.p_m * mf + pw.p_mm * (n - mf)) \
+                    + kw * (pw.p_w * mf + pw.p_mw * (n - mf))
+                self.energy += float(e_pass.sum())
+                self._trace_cycles.append(
+                    self.cycles - 2 * P + 2 * np.arange(1, P + 1, dtype=np.int64))
+                self._trace_energy.append(e_pass)
+                self.events["match"] += int(m.sum())
+                self.events["mismatch"] += int(P) * n - int(m.sum())
+                self.events["write"] += int((kw * mf).sum())
+                self.events["miswrite"] += int((kw * (n - mf)).sum())
 
     def charge_bulk(self, *, cycles: int = 0, compare_cycles: int = 0,
                     write_cycles: int = 0, read_cycles: int = 0,
@@ -547,24 +558,26 @@ class APEngine:
         sliced off before accounting.
         """
         P = sched.n_passes
-        cc, ck, wc, wk = bucket_schedule(sched)
-        if self.backend == "pallas":
-            from repro.kernels.ap_match import ops as _ap_ops
-            self.planes, matched = _ap_ops.run_schedule(
-                self.planes, cc, ck, wc, wk, backend="pallas")
-        elif self.backend in ("megakernel", "megakernel_pallas"):
-            from repro.kernels.ap_megakernel import OpGroup, ops as _mk_ops
-            mk_backend = ("pallas" if self.backend == "megakernel_pallas"
-                          else "jnp")
-            self.planes, self.tag, matched = _mk_ops.run_group(
-                self.planes, self.tag,
-                OpGroup.from_schedule(cc, ck, wc, wk),
-                backend=mk_backend, mesh=self.mesh)
-        else:
-            self.planes, matched = _run_schedule(
-                self.planes, jnp.asarray(cc), jnp.asarray(ck),
-                jnp.asarray(wc), jnp.asarray(wk))
-        self.charge_run(sched, matched[:P])
+        with obs.span("engine/run", passes=P, backend=self.backend):
+            cc, ck, wc, wk = bucket_schedule(sched)
+            if self.backend == "pallas":
+                from repro.kernels.ap_match import ops as _ap_ops
+                self.planes, matched = _ap_ops.run_schedule(
+                    self.planes, cc, ck, wc, wk, backend="pallas")
+            elif self.backend in ("megakernel", "megakernel_pallas"):
+                from repro.kernels.ap_megakernel import OpGroup, ops as _mk_ops
+                mk_backend = ("pallas" if self.backend == "megakernel_pallas"
+                              else "jnp")
+                self.planes, self.tag, matched = _mk_ops.run_group(
+                    self.planes, self.tag,
+                    OpGroup.from_schedule(cc, ck, wc, wk),
+                    backend=mk_backend, mesh=self.mesh)
+            else:
+                self.planes, matched = _run_schedule(
+                    self.planes, jnp.asarray(cc), jnp.asarray(ck),
+                    jnp.asarray(wc), jnp.asarray(wk))
+            matched = matched[:P]
+        self.charge_run(sched, matched)
 
     # -------------------------------------------------- functional bridge
     def state(self) -> APState:

@@ -55,7 +55,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 
 #: stop coarsening below this in-plane size (the coarsest level is
 #: relaxed with palindromic red-black line sweeps, which is exact in the
@@ -165,10 +164,6 @@ def build_levels(F: dict, d_extra, min_n: int = MIN_COARSE_N) -> list:
     while True:
         _, ny, nx = levels[-1][0]["g_pkg"].shape
         if ny % 2 or nx % 2 or min(ny, nx) // 2 < min_n:
-            # hierarchy construction happens at trace time when called
-            # from a jitted driver, so these count builds-per-compile
-            obs.count("mg/hierarchies_built")
-            obs.count(f"mg/hierarchies_built[levels={len(levels)}]")
             return levels
         levels.append(coarsen(*levels[-1], rescale_lateral=True))
 

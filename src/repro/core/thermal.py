@@ -458,7 +458,8 @@ def _solve_fields_guarded(b, F, solver: str, use_pallas: bool,
     counted in ``obs`` under ``thermal/fallback/*``.
     """
     from repro.faults import inject
-    bnorm = float(jnp.linalg.norm(b))
+    with obs.span("sync/bnorm"):
+        bnorm = float(jnp.linalg.norm(b))
     if bnorm == 0.0 or not math.isfinite(bnorm):
         # zero RHS: x = 0 is exact.  A non-finite RHS no backend can fix
         # — report it honestly rather than looping the chain.
@@ -467,14 +468,19 @@ def _solve_fields_guarded(b, F, solver: str, use_pallas: bool,
                                       "rel_residual": resid}
     last = None
     for i, (s, scale) in enumerate(fallback_chain(solver)):
-        if inject.solver_poisoned(s):
-            dT, iters = jnp.full_like(b, jnp.nan), 0
-        else:
-            dT, iters = _solve_fields(b, F, s, use_pallas, tol * scale)
-        resid = float(jnp.linalg.norm(b - apply_operator_fields(dT, F))
-                      / bnorm)
-        last = (dT, int(iters), {"attempts": i + 1, "solved_by": s,
-                                 "rel_residual": resid})
+        with obs.span("thermal/steady/solve", solver=s, attempt=i + 1):
+            if inject.solver_poisoned(s):
+                dT, iters = jnp.full_like(b, jnp.nan), 0
+            else:
+                dT, iters = _solve_fields(b, F, s, use_pallas, tol * scale)
+        with obs.span("thermal/steady/residual"):
+            resid = jnp.linalg.norm(b - apply_operator_fields(dT, F)) / bnorm
+            with obs.span("sync/residual"):
+                resid = float(resid)
+        with obs.span("sync/iters"):
+            iters = int(iters)
+        last = (dT, iters, {"attempts": i + 1, "solved_by": s,
+                            "rel_residual": resid})
         if math.isfinite(resid) and resid <= HEALTH_RTOL:
             if i:
                 obs.count("thermal/fallback/recovered")
@@ -508,16 +514,20 @@ def steady_state_stats(power: np.ndarray | jax.Array, grid: Grid,
     """
     with obs.span("thermal/steady", solver=solver,
                   shape=f"{grid.n_layers}x{grid.dom_ny}x{grid.dom_nx}"):
-        F = grid.fields()
-        power = grid.pad_power(power)
-        if not bool(jnp.isfinite(power).all()):
-            raise ValueError(
-                "steady_state: power map has non-finite cells; refusing "
-                "to solve — NaN temperatures would silently poison every "
-                "downstream verdict")
-        m = grid.margin
-        if m:
-            power = jnp.pad(power, ((0, 0), (m, m), (m, m)))
+        with obs.span("thermal/steady/fields"):
+            F = grid.fields()
+        with obs.span("thermal/steady/check_power"):
+            power = grid.pad_power(power)
+            with obs.span("sync/finite"):
+                finite = bool(jnp.isfinite(power).all())
+            if not finite:
+                raise ValueError(
+                    "steady_state: power map has non-finite cells; "
+                    "refusing to solve — NaN temperatures would silently "
+                    "poison every downstream verdict")
+            m = grid.margin
+            if m:
+                power = jnp.pad(power, ((0, 0), (m, m), (m, m)))
         dT, iters, fstats = _solve_fields_guarded(power, F, solver,
                                                   use_pallas, tol)
         n_die = grid.n_die_layers
@@ -529,10 +539,8 @@ def steady_state_stats(power: np.ndarray | jax.Array, grid: Grid,
                  "rel_residual": fstats["rel_residual"],
                  "attempts": fstats["attempts"],
                  "solved_by": fstats["solved_by"]}
-    obs.count("thermal/steady/solves")
-    obs.observe(f"thermal/steady/iterations[{solver}]", stats["iterations"])
-    obs.observe("thermal/steady/rel_residual", stats["rel_residual"])
-    return dT + t_amb, stats
+        T = dT + t_amb
+    return T, stats
 
 
 def steady_state(power: np.ndarray | jax.Array, grid: Grid,
@@ -605,8 +613,8 @@ def _implicit_scan(dT0, power, A, solve, n_steps: int, lhs=None):
     With ``lhs`` (the theta-scheme LHS closure) given, the per-step ys
     also carry the TRUE relative linear residual of each inner solve,
     ``||rhs - lhs(delta)|| / ||rhs||`` — one extra matvec per step, paid
-    only on the telemetry path (``obs`` enabled), never in the default
-    compiled program.
+    only by callers that ask for the residuals (``with_residuals``),
+    never in the default compiled program.
     """
 
     def step(dTc, _):
@@ -692,7 +700,6 @@ def transient_implicit_fields(T0, power, F: dict, cap3, dt, n_steps: int,
     (static) appends per-step relative linear residuals:
     ``(T, peaks, res)``.
     """
-    obs.count("thermal/retrace/transient_fields")
     A = lambda v: apply_operator_fields(v, F)
     solve = implicit_lhs_solver(A, F, cap3, dt, theta, solver=solver,
                                 n_cg=n_cg, n_mg=n_mg,
@@ -709,18 +716,19 @@ def transient_implicit_fields(T0, power, F: dict, cap3, dt, n_steps: int,
 def transient_solve_implicit(power, grid: Grid, t_end: float,
                              n_steps: int, theta: float = 1.0,
                              t_amb: float = AMBIENT_C, n_cg: int = 50,
-                             solver: str = "pcg", n_mg: int = 3
-                             ) -> tuple[jax.Array, jax.Array]:
+                             solver: str = "pcg", n_mg: int = 3,
+                             with_residuals: bool = False):
     """Implicit counterpart of :func:`transient_solve` with a chosen step
     count (the point: n_steps can be 10-1000x below the explicit bound).
     ``solver="mg"`` runs the multigrid inner solve on the fields form of
     the same stack.
 
-    With ``obs`` enabled the per-step inner-solve residuals are computed
-    on device (one extra matvec per step) and recorded under
-    ``thermal/transient/*``; the public return stays the 2-tuple.
+    Returns ``(T, peaks)``.  ``with_residuals=True`` also computes the
+    per-step relative inner-solve residuals on device (one extra matvec
+    per step, another compiled program), returns ``(T, peaks, res)``
+    and records them under ``thermal/transient/*`` when ``obs`` is
+    enabled.
     """
-    wres = obs.is_enabled()
     power = grid.pad_power(power)
     dt = t_end / n_steps
     T0 = jnp.full(power.shape, t_amb, jnp.float32)
@@ -731,20 +739,19 @@ def transient_solve_implicit(power, grid: Grid, t_end: float,
             out = transient_implicit_fields(T0, power, F, cap3, dt,
                                             n_steps, theta, t_amb, n_cg,
                                             solver="mg", n_mg=n_mg,
-                                            with_residuals=wres)
+                                            with_residuals=with_residuals)
         else:
             g = grid.conductances()
             cap = grid.capacities()
             out = transient_implicit(T0, power, g["g_lat"], g["g_vert"],
                                      g["g_pkg"], cap, dt, n_steps, theta,
-                                     t_amb, n_cg, with_residuals=wres)
-    if wres:
-        T, peaks, res = out
+                                     t_amb, n_cg,
+                                     with_residuals=with_residuals)
+    if with_residuals and obs.is_enabled():
         obs.count("thermal/transient/solves")
         obs.count("thermal/transient/steps", n_steps)
         obs.count("thermal/transient/inner_iterations",
                   n_steps * (n_mg if solver == "mg" else n_cg))
         obs.observe_many("thermal/transient/step_rel_residual",
-                         np.asarray(res, np.float64))
-        return T, peaks
+                         np.asarray(out[2], np.float64))
     return out

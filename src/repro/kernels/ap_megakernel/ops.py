@@ -13,11 +13,6 @@ optionally sharded over the packed word-lane axis with ``mesh=`` (a 1D
 device holds a plane/tag slice, responder popcounts are ``psum``-ed
 before any conditional consumes them, so results are bitwise invariant
 to the device count.
-
-Launch counters: every host-level dispatch bumps
-``kernels/launch/ap_megakernel`` (+ per-backend variant) in ``repro.obs``
-— that is the kernel-launch budget the megakernel path is meant to
-shrink, and benches snapshot it.
 """
 from __future__ import annotations
 
@@ -27,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import obs
 from repro.kernels.ap_megakernel import ref
 from repro.kernels.ap_megakernel.kernel import run_group_kernel
 from repro.kernels.ap_megakernel.ref import OpGroup
@@ -35,9 +29,6 @@ from repro.kernels.ap_megakernel.ref import OpGroup
 
 @jax.jit
 def _run_group_jnp(planes, tag, op, cond, enabled, cc, ck, wc, wk):
-    obs.count("kernels/retrace/ap_megakernel")
-    obs.count(f"kernels/retrace/ap_megakernel[P={op.shape[0]},"
-              f"Kc={cc.shape[1]},Kw={wc.shape[1]}]")
     return ref.group_scan(planes, tag, (op, cond, cc, ck, wc, wk), enabled)
 
 
@@ -64,7 +55,6 @@ def _sharded_runner(mesh):
 
     @jax.jit
     def run(planes, tag, op, cond, enabled, cc, ck, wc, wk):
-        obs.count("kernels/retrace/ap_megakernel_sharded")
         return mapped(planes, tag, op, cond, enabled, cc, ck, wc, wk)
 
     return run
@@ -79,9 +69,6 @@ def run_group(planes, tag, group: OpGroup, enabled=None, *,
     mesh    : optional 1D 'lanes' mesh — shards planes/tag over devices
               (jnp backend only; n_lanes must divide evenly)
     """
-    obs.count("kernels/launch/ap_megakernel")
-    obs.count(f"kernels/launch/ap_megakernel/{backend}"
-              + ("_sharded" if mesh is not None else ""))
     op, cond, cc, ck, wc, wk = (jnp.asarray(t) for t in group.tables())
     if enabled is None:
         enabled = jnp.ones(group.n_ops, jnp.bool_)

@@ -2,24 +2,36 @@
 
 One process-local metrics registry (counters / gauges / histograms
 with p50/p95/p99 summaries, :mod:`repro.obs.registry`) plus scoped
-wall-clock spans exported as Chrome trace-event JSON loadable in
-Perfetto (:mod:`repro.obs.trace`).  Everything funnels through this
-module's functions so call sites stay one line::
+spans.  Everything funnels through this module's functions so call
+sites stay one line::
 
     from repro import obs
 
     obs.count("sweep/cache/hit")
     obs.observe("serving/request_latency_s", 0.132)
-    with obs.span("sweep/replay", cases=24):
+    with obs.span("feedback/replay", cases=24):
         ...
+
+A span goes to two sinks, each only while it is on:
+
+* a ``jax.profiler`` session that is recording: the span is a
+  ``jax.profiler.TraceAnnotation`` of the same name (its keyword
+  arguments become the event's metadata), so it lands in the
+  profiler's ``.xplane.pb`` on the device trace's clock — whether or
+  not the registry is enabled;
+* the registry, when :func:`is_enabled`: a Chrome trace-event
+  (:mod:`repro.obs.trace`, loadable in Perfetto) and a ``span/<name>``
+  duration histogram.
 
 **Disabled mode is a strict no-op**: when :func:`is_enabled` is False
 (the default; enable with ``REPRO_OBS=1`` or :func:`enable`), every
 recording function returns immediately without touching the registry,
-and :func:`span` hands back a shared null context manager — no
-allocation, no clock read.  The benchmark drivers enable obs
-(``benchmarks/_record.Recorder`` does it on construction) and gate the
-enabled-vs-disabled overhead at ≤ 1.05× in ``baseline.json``.
+and with no profiler recording :func:`span` hands back a shared null
+context manager — no allocation, no clock read.  Neither sink changes
+a compiled program or adds a host sync.  The scripts under
+``benchmarks/`` enable obs (``benchmarks/_record.Recorder`` does it on
+construction) and gate the enabled-vs-disabled overhead at ≤ 1.05× in
+``baseline.json``.
 
 **jit-safety rules** (docs/observability.md):
 
@@ -38,6 +50,8 @@ from __future__ import annotations
 
 import os
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.registry import Registry
 from repro.obs.trace import Tracer
@@ -150,12 +164,44 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _BothSpans:
+    """A profiler annotation and a registry span, opened together."""
+    __slots__ = ("_annotation", "_span")
+
+    def __init__(self, annotation, span):
+        self._annotation, self._span = annotation, span
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+
+
 def span(name: str, **args):
-    """Scoped wall-clock span.  Nested spans stack per thread; each
+    """Scoped span, written to every sink that is on (module docstring).
+
+    While a ``jax.profiler`` session records, it is a
+    ``TraceAnnotation`` named ``name`` with ``args`` as metadata.  With
+    the registry enabled, nested spans stack per thread and each
     completed span becomes a Chrome trace event AND feeds the
     ``span/<name>`` duration histogram (so p50/p95/p99 of any span
-    show up in :func:`snapshot`).  Extra keyword arguments land in the
-    event's ``args``."""
+    show up in :func:`snapshot`); extra keyword arguments land in the
+    event's ``args``.  With neither on it returns the shared null
+    span.
+
+    A span named ``sync/<what>`` wraps one blocking device-to-host
+    transfer: their count in a trace is the program's count of host
+    syncs (docs/observability.md).
+    """
+    if TraceAnnotation.is_enabled():
+        annotation = TraceAnnotation(name, **args)
+        if not _enabled:
+            return annotation
+        return _BothSpans(annotation, _tracer.span(name, **args))
     if not _enabled:
         return _NULL_SPAN
     return _tracer.span(name, **args)

@@ -612,9 +612,24 @@ def replay_cases(cases, spec: StackSpec, fb: FeedbackParams, grid_n: int,
             n_die=spec.n_die_layers, steps_per_interval=steps_per_interval,
             n_cg=n_cg, margin=margin, use_pallas=use_pallas, solver=solver,
             n_mg=n_mg)
+    base_ref = dram.DRAMFloorplan(die_w_mm=1.0).base_refresh_W() \
+        * len(spec.dram_layers)
+    with obs.span("feedback/reports", cases=len(labels)):
+        with obs.span("sync/reports"):
+            outs = jax.device_get((peaks, mins, res, thr, ref_W, leak_W,
+                                   dyn_W))
+        peaks, mins, res, thr, ref_W, leak_W, dyn_W = outs
+        reports = {
+            label: StackReport(
+                label=label, interval_s=interval_dt, spec=spec,
+                peak_C=peaks[i], min_C=mins[i], residual_C=res[i],
+                throttle=thr[i], refresh_W=ref_W[i], leak_W=leak_W[i],
+                base_refresh_W=base_ref, tol_C=fb.picard_tol_C,
+                dyn_W=dyn_W[i])
+            for i, label in enumerate(labels)}
     if obs.is_enabled():
-        res_h, thr_h = np.asarray(res, np.float64), np.asarray(thr,
-                                                               np.float64)
+        res_h = np.asarray(res, np.float64)
+        thr_h = np.asarray(thr, np.float64)
         n_int = res_h.shape[-1] if res_h.ndim else 0
         obs.count("feedback/intervals", len(labels) * n_int)
         obs.count("feedback/picard_iterations",
@@ -630,17 +645,7 @@ def replay_cases(cases, spec: StackSpec, fb: FeedbackParams, grid_n: int,
         resid = pol.residency(thr_h)
         for op, n in (resid or {}).items():
             obs.count(f"policy/{pol.name}/residency/{op}", n)
-    base_ref = dram.DRAMFloorplan(die_w_mm=1.0).base_refresh_W() \
-        * len(spec.dram_layers)
-    return {
-        label: StackReport(
-            label=label, interval_s=interval_dt, spec=spec,
-            peak_C=np.asarray(peaks[i]), min_C=np.asarray(mins[i]),
-            residual_C=np.asarray(res[i]), throttle=np.asarray(thr[i]),
-            refresh_W=np.asarray(ref_W[i]), leak_W=np.asarray(leak_W[i]),
-            base_refresh_W=base_ref, tol_C=fb.picard_tol_C,
-            dyn_W=np.asarray(dyn_W[i]))
-        for i, label in enumerate(labels)}
+    return reports
 
 
 # ---------------------------------------------------------------------------

@@ -161,15 +161,12 @@ def _run_group(spec: SweepSpec, points: list[SweepPoint], n_dram: int,
                 cases.append((f"{p.label}/{mc}", feedback.assemble_case(
                     dp, p.workload, mc, stack_spec, params, spec.grid_n,
                     trace, margin)))
-    obs.count("sweep/cases", len(cases))
 
-    with obs.span("sweep/replay", n_dram=n_dram, fb=fb_mode,
-                  policy=policy, cases=len(cases)):
-        reports = feedback.replay_cases(
-            cases, stack_spec, fb, spec.grid_n, interval_dt,
-            theta=spec.theta, steps_per_interval=spec.steps_per_interval,
-            n_cg=spec.n_cg, margin=margin, solver=spec.solver,
-            n_mg=spec.n_mg, n_shards=n_shards)
+    reports = feedback.replay_cases(
+        cases, stack_spec, fb, spec.grid_n, interval_dt,
+        theta=spec.theta, steps_per_interval=spec.steps_per_interval,
+        n_cg=spec.n_cg, margin=margin, solver=spec.solver,
+        n_mg=spec.n_mg, n_shards=n_shards)
     return {(p, mc): SweepRecord(point=p, machine=mc,
                                  report=reports[f"{p.label}/{mc}"])
             for p, mc in keys}
@@ -237,21 +234,20 @@ def run_sweep(spec: SweepSpec, cache_dir=None, use_cache: bool = True,
         by_group[(p.n_dram, p.fb_mode, pol)].append(p)
 
     results: dict[tuple[SweepPoint, str], SweepRecord] = {}
-    with obs.span("sweep/run", groups=len(by_group)):
-        for (n_dram, fb_mode, pol), pts in sorted(by_group.items()):
-            with obs.span("sweep/group", n_dram=n_dram, fb=fb_mode,
-                          policy=pol, points=len(pts)):
-                # per-group failure isolation: one group raising (bad
-                # power inputs, a faulted replay, a solver blow-up)
-                # must not kill the other groups' results — it is
-                # demoted to NaN placeholder records marked FAILED
-                try:
-                    results.update(_run_group(spec, pts, n_dram, fb_mode,
-                                              pol, params, n_shards))
-                except (ValueError, FloatingPointError) as e:
-                    obs.count("sweep/groups_failed")
-                    results.update(_failed_group(
-                        spec, pts, n_dram, fb_mode, pol, params, str(e)))
+    for (n_dram, fb_mode, pol), pts in sorted(by_group.items()):
+        with obs.span("sweep/group", n_dram=n_dram, fb=fb_mode,
+                      policy=pol, points=len(pts)):
+            # per-group failure isolation: one group raising (bad
+            # power inputs, a faulted replay, a solver blow-up)
+            # must not kill the other groups' results — it is
+            # demoted to NaN placeholder records marked FAILED
+            try:
+                results.update(_run_group(spec, pts, n_dram, fb_mode,
+                                          pol, params, n_shards))
+            except (ValueError, FloatingPointError) as e:
+                obs.count("sweep/groups_failed")
+                results.update(_failed_group(
+                    spec, pts, n_dram, fb_mode, pol, params, str(e)))
 
     records = tuple(results[(p, mc)] for p in spec.points()
                     for mc in spec.machines)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.core import bitplane as bp
 from repro.core import isa
 from repro.core import engine as E
@@ -73,9 +72,6 @@ class MinExtractTrace:
 def _min_extract_program(state, copy_cc, copy_ck, copy_wc, copy_wk,
                          remaining, *, val_cols, active_col, cand_col,
                          rounds, readout):
-    obs.count("workloads/retrace/min_extract")
-    obs.count(f"workloads/retrace/min_extract[m={len(val_cols)},"
-              f"rounds={rounds},readout={readout}]")
     cand = jnp.array([cand_col], jnp.int32)
     active = jnp.array([active_col], jnp.int32)
     one = jnp.array([1], jnp.uint32)
@@ -185,10 +181,6 @@ def tagged_rows(tag_row: np.ndarray) -> np.ndarray:
 
 @jax.jit
 def _count_probes_program(state, cols, keys, real):
-    obs.count("workloads/retrace/count_probes")
-    obs.count(f"workloads/retrace/count_probes[n={cols.shape[0]},"
-              f"k={cols.shape[1]}]")
-
     def body(st0, xs):
         cc, kk, is_real = xs
         st, matched = E.state_compare(st0, cc, kk)
@@ -332,9 +324,6 @@ def _mk_rounds_impl(state, op, cond, cc, ck, wc, wk, remaining, rounds,
 @partial(jax.jit, static_argnames=("rounds", "readout"))
 def _mk_rounds_program(state, op, cond, cc, ck, wc, wk, remaining, *,
                        rounds, readout):
-    obs.count("workloads/retrace/min_extract_mk")
-    obs.count(f"workloads/retrace/min_extract_mk[P={op.shape[0]},"
-              f"rounds={rounds},readout={readout}]")
     return _mk_rounds_impl(state, op, cond, cc, ck, wc, wk, remaining,
                            rounds, readout, axis_name=None)
 
@@ -360,7 +349,6 @@ def _mk_rounds_sharded(mesh, rounds, readout):
 
     @jax.jit
     def run(state, op, cond, cc, ck, wc, wk, remaining):
-        obs.count("workloads/retrace/min_extract_mk_sharded")
         return mapped(state, op, cond, cc, ck, wc, wk, remaining)
 
     return run
@@ -375,8 +363,6 @@ def min_extract_rounds_mk(eng: APEngine, val: Field, active: Field,
     so the replay layer is shared."""
     copy_sched = isa.copy(cand, active)
     group = _min_extract_group(copy_sched, val, active, cand, readout)
-    obs.count("kernels/launch/ap_megakernel")
-    obs.count("kernels/launch/ap_megakernel/min_extract_rounds")
     tables = tuple(jnp.asarray(t) for t in group.tables())
     if eng.mesh is not None:
         state, ys = _mk_rounds_sharded(eng.mesh, rounds, readout)(

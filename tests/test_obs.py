@@ -1,6 +1,8 @@
 """The observability layer: registry math, spans, disabled-mode no-op,
 Chrome trace-event export."""
 import json
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -38,6 +40,8 @@ def test_disabled_mode_is_strict_noop():
 
 
 def test_disabled_span_is_shared_null_singleton():
+    from jax.profiler import TraceAnnotation
+    assert not TraceAnnotation.is_enabled()     # no profiler recording
     a, b = obs.span("a"), obs.span("b", attr=1)
     assert a is b                   # no per-call allocation when off
 
@@ -192,3 +196,121 @@ def test_count_inside_jit_fires_per_trace_not_per_call():
         assert obs.value("test/retrace/f") == 1
         f(jnp.zeros(5))             # new shape: one more trace
         assert obs.value("test/retrace/f") == 2
+
+
+# ------------------------------------------------ the profiler's trace
+
+@pytest.fixture(scope="module")
+def program_spans():
+    """The benchmark's reader of program spans in a profiler trace."""
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import program_spans
+    return program_spans
+
+
+def _tiny_steady():
+    from repro.core import thermal
+    grid = thermal.Grid(die_w=2.3e-3, ny=8, nx=8, margin=2)
+    power = np.full((4, 8, 8), 0.01, np.float32)
+    return lambda: thermal.steady_state_stats(power, grid, solver="mg")
+
+
+def _profiled_spans(fn, trace_dir, program_spans):
+    """Program spans of one call of ``fn`` (warmed first) under the
+    profiler, nested: [(start, end, path, thread, parent), ...]."""
+    import jax
+    fn()
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    trace = program_spans.from_xplane(str(trace_dir))
+    return program_spans.nest(trace["program_spans"])
+
+
+def test_spans_reach_the_profiler_trace_with_obs_off(tmp_path,
+                                                      program_spans):
+    spans = _profiled_spans(_tiny_steady(), tmp_path, program_spans)
+    paths = [p for _, _, p, _, _ in spans]
+    steady = "thermal/steady"
+    assert sorted(paths) == sorted([
+        (steady,),
+        (steady, "thermal/steady/fields"),
+        (steady, "thermal/steady/check_power"),
+        (steady, "thermal/steady/check_power", "sync/finite"),
+        (steady, "sync/bnorm"),
+        (steady, "thermal/steady/solve"),
+        (steady, "thermal/steady/residual"),
+        (steady, "thermal/steady/residual", "sync/residual"),
+        (steady, "sync/iters")])
+    assert obs.snapshot() == {"counters": {}, "gauges": {},
+                              "histograms": {}}
+
+
+def test_obs_adds_no_sync_to_a_steady_solve(tmp_path, program_spans):
+    solve = _tiny_steady()
+    syncs = {}
+    for on in (False, True):
+        with obs.scoped(on):
+            spans = _profiled_spans(solve, tmp_path / str(on),
+                                    program_spans)
+        syncs[on] = sum(1 for _, _, p, _, _ in spans
+                        if p[-1].startswith("sync/"))
+    assert syncs[False] == syncs[True] == 4
+    # the registry saw the same syncs, in both of its calls
+    hist = obs.snapshot()["histograms"]
+    assert sum(h["count"] for k, h in hist.items()
+               if k.startswith("span/sync/")) == 2 * syncs[True]
+
+
+def _lower_transient_fields():
+    import jax.numpy as jnp
+    from repro.core import thermal
+    grid = thermal.Grid(die_w=2.3e-3, ny=8, nx=8, margin=4)
+    T0 = jnp.zeros((grid.n_layers, grid.dom_ny, grid.dom_nx), jnp.float32)
+    return thermal.transient_implicit_fields.lower(
+        T0, T0, grid.fields(), grid.capacity_field(), 1e-3, 3,
+        solver="mg").as_text()
+
+
+def _lower_closed_loop():
+    import jax.numpy as jnp
+    from repro.core import cosim
+    from repro.core import models as M
+    from repro.stack import feedback
+    from repro.stack.spec import PAPER_STACK, dram_on_logic
+    spec = dram_on_logic(1)
+    dp = cosim.comparable_design_point("dmm")
+    trace = cosim.simd_phase_trace(M.WORKLOADS["dmm"], dp, 4)
+    dyn, l0, r0, lm, F, cap3 = feedback.assemble_case(
+        dp, "dmm", "simd", spec, PAPER_STACK, 8, trace, 2)
+    batch = [jnp.asarray(x)[None] for x in (dyn, l0, r0, lm)]
+    Fb = {k: v[None] for k, v in F.items()}
+    return feedback.closed_loop_batch.lower(
+        *batch, Fb, cap3[None], 1e-3, fb=feedback.FeedbackParams(),
+        die_n=8, n_die=spec.n_die_layers, margin=2, n_cg=5).as_text()
+
+
+@pytest.mark.parametrize("lower", [_lower_transient_fields,
+                                   _lower_closed_loop],
+                         ids=["transient_implicit_fields",
+                              "closed_loop_batch"])
+@pytest.mark.parametrize("sink", ["registry", "profiler"])
+def test_obs_changes_no_compiled_program(lower, sink, tmp_path):
+    import jax
+    jax.clear_caches()                  # trace afresh, not from the cache
+    off = lower()
+    jax.clear_caches()
+    if sink == "registry":
+        with obs.scoped():
+            on = lower()
+    else:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            on = lower()
+        finally:
+            jax.profiler.stop_trace()
+    assert on == off
