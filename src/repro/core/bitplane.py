@@ -41,38 +41,120 @@ def alloc_planes(n_bits: int, n_words: int) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# host <-> bitplane conversion
+# word <-> bit-plane transposes.  The host only casts: uint64 words split
+# into uint32 halves (JAX runs without x64, so >32-bit fields cross as a
+# low and a high half).  The transpose itself runs on the device, one
+# compiled program per field width; the field's start column is a traced
+# operand, so every field of one width shares that program.
 # ---------------------------------------------------------------------------
+
+def _check_width(n_bits: int) -> None:
+    if n_bits > 64:
+        raise ValueError(
+            f"fields wider than 64 bits do not fit uint64 host words "
+            f"(got width {n_bits}); split the value across fields")
+
+
+def split_words(values, n_bits: int) -> tuple[np.ndarray, np.ndarray | None]:
+    """Host cast of integer words to uint32 halves for the device.
+
+    Returns ``(lo, hi)``: the low 32 bits of every word, and the high 32
+    bits where ``n_bits > 32`` (else ``None``).  Bits at and above
+    ``n_bits`` are ignored by the transpose.
+    """
+    _check_width(n_bits)
+    vals = np.asarray(jax.device_get(values))
+    if vals.dtype != np.uint64:
+        vals = vals.astype(np.uint64)
+    lo = vals.astype(np.uint32)
+    hi = (vals >> np.uint64(32)).astype(np.uint32) if n_bits > 32 else None
+    return lo, hi
+
+
+def widen_words(lo: np.ndarray, hi: np.ndarray | None = None) -> np.ndarray:
+    """Host inverse of :func:`split_words`: uint32 halves -> uint64 words."""
+    vals = np.asarray(lo).astype(np.uint64)
+    if hi is not None:
+        vals |= np.asarray(hi).astype(np.uint64) << np.uint64(32)
+    return vals
+
+
+def _pack_u32(words: jax.Array, n_bits: int) -> jax.Array:
+    """uint32 words [n_words] -> planes of their low ``n_bits`` bits."""
+    w = words.reshape(n_lanes(words.shape[0]), LANE).T        # [LANE, nl]
+    bit = jnp.arange(n_bits, dtype=_U32)[:, None, None]
+    pos = jnp.arange(LANE, dtype=_U32)[None, :, None]
+    return (((w[None] >> bit) & 1) << pos).sum(axis=1, dtype=_U32)
+
+
+def _unpack_u32(planes: jax.Array) -> jax.Array:
+    """Planes [k <= 32, nl] -> uint32 words [nl * 32]."""
+    pos = jnp.arange(LANE, dtype=_U32)[:, None, None]
+    bit = jnp.arange(planes.shape[0], dtype=_U32)[None, :, None]
+    w = (((planes[None] >> pos) & 1) << bit).sum(axis=1, dtype=_U32)
+    return w.T.reshape(-1)                                     # [nl * LANE]
+
+
+def _words_to_planes(lo, hi, n_bits: int) -> jax.Array:
+    if n_bits <= 32:
+        return _pack_u32(lo, n_bits)
+    return jnp.concatenate([_pack_u32(lo, 32), _pack_u32(hi, n_bits - 32)])
+
+
+_pack_device = jax.jit(_words_to_planes, static_argnames=("n_bits",))
+
+
+@partial(jax.jit, static_argnames=("n_bits",))
+def _load_device(planes, lo, hi, start, n_bits: int) -> jax.Array:
+    sub = _words_to_planes(lo, hi, n_bits)
+    return jax.lax.dynamic_update_slice(planes, sub, (start, 0))
+
+
+@partial(jax.jit, static_argnames=("n_bits",))
+def _read_device(planes, start, n_bits: int):
+    sub = jax.lax.dynamic_slice_in_dim(planes, start, n_bits)
+    if n_bits <= 32:
+        return _unpack_u32(sub), None
+    return _unpack_u32(sub[:32]), _unpack_u32(sub[32:])
+
+
+def load_words(planes: jax.Array, values, start: int,
+               n_bits: int) -> jax.Array:
+    """``planes`` with words ``values[n_words]`` stored in bit-columns
+    ``[start, start + n_bits)``: one host cast, one upload of uint32
+    halves and one device program (transpose fused with the store).  The
+    result keeps ``planes``' sharding."""
+    lo, hi = split_words(values, n_bits)
+    return _load_device(planes, lo, hi, start, n_bits=n_bits)
+
+
+def field_words(planes: jax.Array, start: int, n_bits: int):
+    """Device-side read of bit-columns ``[start, start + n_bits)`` as
+    uint32 word halves ``(lo, hi)`` (``hi`` None up to 32 bits); bring
+    them to the host with one ``jax.device_get`` and :func:`widen_words`."""
+    _check_width(n_bits)
+    return _read_device(planes, start, n_bits=n_bits)
+
 
 def pack_words(values: np.ndarray | jax.Array, n_bits: int) -> jax.Array:
     """Pack integer words ``values[n_words]`` into bit planes [n_bits, n_words/32].
 
     Bit ``i`` of word ``w`` lands in ``planes[i, w // 32]`` at lane-bit ``w % 32``.
-    Host-side (numpy) so >32-bit fields work without jax_enable_x64.
+    The host casts the words to uint32 halves (:func:`split_words`); the
+    transpose runs on the device.
     """
-    if n_bits > 64:
-        raise ValueError(
-            f"fields wider than 64 bits cannot be packed from uint64 host "
-            f"words (got width {n_bits}); split the value across fields")
-    values = np.asarray(jax.device_get(values)).astype(np.uint64)
-    n_words = values.shape[0]
-    nl = n_lanes(n_words)
-    bits = (values[None, :] >> np.arange(n_bits, dtype=np.uint64)[:, None]) & 1
-    bits = bits.astype(np.uint32).reshape(n_bits, nl, LANE)
-    shifts = np.arange(LANE, dtype=np.uint32)
-    packed = (bits << shifts[None, None, :]).sum(axis=-1, dtype=np.uint32)
-    return jnp.asarray(packed)
+    lo, hi = split_words(values, n_bits)
+    return _pack_device(lo, hi, n_bits=n_bits)
 
 
 def unpack_words(planes: jax.Array, out_dtype=np.uint64) -> np.ndarray:
-    """Inverse of :func:`pack_words` -> integer words [n_words] (host numpy)."""
-    pl = np.asarray(jax.device_get(planes))
-    n_bits, nl = pl.shape
-    shifts = np.arange(LANE, dtype=np.uint32)
-    bits = (pl[:, :, None] >> shifts[None, None, :]) & 1  # [bits, nl, LANE]
-    bits = bits.reshape(n_bits, nl * LANE).astype(out_dtype)
-    weights = (out_dtype(1) << np.arange(n_bits, dtype=out_dtype))
-    return (bits * weights[:, None]).sum(axis=0, dtype=out_dtype)
+    """Inverse of :func:`pack_words` -> integer words [n_words] (host numpy).
+
+    The transpose runs on the device; the host widens uint32 halves.
+    """
+    planes = jnp.asarray(planes)
+    halves = jax.device_get(field_words(planes, 0, planes.shape[0]))
+    return widen_words(*halves).astype(out_dtype, copy=False)
 
 
 def pack_bits(bitvec: np.ndarray | jax.Array) -> jax.Array:
@@ -139,14 +221,6 @@ def broadcast_write(planes: jax.Array, cols: jax.Array, key: jax.Array) -> jax.A
 def write_column_bits(planes: jax.Array, col: int, bits: jax.Array) -> jax.Array:
     """Host-side load of a full per-word bit column (data load, not an AP op)."""
     return planes.at[col].set(bits)
-
-
-@partial(jax.jit, static_argnames=("start",))
-def set_field_planes(planes: jax.Array, sub: jax.Array,
-                     start: int) -> jax.Array:
-    """Store packed field planes ``sub`` at bit-column ``start`` (jitted:
-    an un-jitted scatter dispatch costs ~1 ms per field load on CPU)."""
-    return jax.lax.dynamic_update_slice(planes, sub, (start, 0))
 
 
 # ---------------------------------------------------------------------------

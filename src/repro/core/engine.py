@@ -370,18 +370,18 @@ class APEngine:
             raise ValueError(f"expected ({self.n_words},), got {vals.shape}")
         with obs.span("engine/load", width=field.width):
             with obs.span("engine/pack"):
-                sub = bp.pack_words(vals, field.width)
-            self.planes = bp.set_field_planes(self.planes, sub, field.start)
+                self.planes = bp.load_words(self.planes, vals, field.start,
+                                            field.width)
 
     def read(self, field: Field, signed: bool = False) -> np.ndarray:
         """Host-side readback of a field for all words (charges n read cycles)."""
         with obs.span("engine/read", width=field.width):
             self.charge_read(self.n_words)
-            sub = self.planes[field.start:field.start + field.width]
+            halves = bp.field_words(self.planes, field.start, field.width)
             with obs.span("sync/read"):
-                sub = np.asarray(sub)
+                halves = jax.device_get(halves)
             with obs.span("engine/unpack"):
-                vals = bp.unpack_words(sub)
+                vals = bp.widen_words(*halves)
         if signed and field.width < 64:
             sign = vals >> (field.width - 1)
             vals = vals.astype(np.int64) - (sign.astype(np.int64) << field.width)
@@ -389,8 +389,8 @@ class APEngine:
 
     def peek(self, field: Field) -> np.ndarray:
         """Readback WITHOUT charging cycles (debug / test oracle only)."""
-        sub = self.planes[field.start:field.start + field.width]
-        return np.asarray(bp.unpack_words(sub))
+        return bp.widen_words(*jax.device_get(
+            bp.field_words(self.planes, field.start, field.width)))
 
     def read_tagged(self, field: Field) -> tuple[np.ndarray, np.ndarray]:
         """Sequential readout of ``field`` for the currently TAGGED rows.
@@ -401,9 +401,7 @@ class APEngine:
         """
         rows = np.where(np.asarray(bp.unpack_bits(self.tag)))[0]
         self.charge_read(len(rows))
-        sub = self.planes[field.start:field.start + field.width]
-        vals = np.asarray(bp.unpack_words(sub))[rows]
-        return rows, vals
+        return rows, self.peek(field)[rows]
 
     # ------------------------------------------------------ silicon ops
     def compare(self, cols: Sequence[int], key: Sequence[int],

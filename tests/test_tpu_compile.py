@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import isa, multigrid, thermal
+from repro.core import bitplane as bp, isa, multigrid, thermal
 from repro.core.engine import APEngine, bucket_schedule
 from repro.kernels.ap_match import kernel as ap_match
 from repro.kernels.ap_megakernel import OpGroup, kernel as megakernel
@@ -162,6 +162,21 @@ def test_megakernel_conditional_compiles(one_chip):
         *a, interpret=False, conditional=True),
         *_group_args(group, n_bits, SORT_WORDS // 32, one_chip))
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("n_bits", [16, 40])
+def test_word_transposes_fuse(one_chip, n_bits):
+    """A field load and read at 2^20 words: the transposes fuse into
+    their stores, so no [bits, 32, lanes] temporary reaches HBM."""
+    planes = jax.ShapeDtypeStruct((256, AP_WORDS // 32), jnp.uint32,
+                                  sharding=one_chip)
+    half = jax.ShapeDtypeStruct((AP_WORDS,), jnp.uint32, sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    load = bp._load_device.lower(planes, half, half if n_bits > 32 else None,
+                                 start, n_bits=n_bits).compile()
+    read = bp._read_device.lower(planes, start, n_bits=n_bits).compile()
+    for compiled in (load, read):
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 # -------------------------------------------- f32-exact solver reductions
