@@ -7,8 +7,8 @@ paper's AP machine model (``core/models.py``).  For one ``configs/``
 entry and a request shape it produces
 
 * per-request prefill/decode FLOPs and the per-decode-step byte
-  traffic (active-parameter stream + per-sequence KV/state reads, the
-  ``models/serve.py`` batching semantics: one parameter read per step is
+  traffic (weight stream + per-sequence KV/state reads, the
+  ``models/serve.py`` batching semantics: one weight read per step is
   amortized over the whole decode batch);
 * the decode arithmetic intensity AI(B) [flop/word] as a function of
   batch size — batching raises AI because the parameter stream is
@@ -17,6 +17,25 @@ entry and a request shape it produces
   same inverse-AI anchoring the suite workloads use
   (``models.derived_workload``), which gives the serving scenario its
   same-performance AP/SIMD design pair and DRAM-traffic figure.
+
+Costs by mechanism, each derived from the config (``cfg.moe``,
+``cfg.mla``) and the model's parameter shapes, with no branch on the
+model's name:
+
+* **Routed experts at batch B.**  A decode step streams every routed
+  expert that any of its B tokens routes to.  Under uniform routing
+  (each token picks k of E experts at random; a random router is close
+  to that, tests/test_serving_moe_mla.py) a MoE layer touches
+  E·(1 − (1 − k/E)^B) experts, k at B = 1 and all E as B grows.
+  Shared experts, dense layers, attention, norms, embedding and head
+  are read once a step.
+* **Latent attention (MLA).**  Decode runs the absorbed form: per
+  layer and context position 2H(kv_lora + qk_rope) FLOPs of scores over
+  the latent and the rope key and 2H·kv_lora of values.  Prefill runs
+  the expanded form: 2H(qk_nope + qk_rope) + 2H·v_dim FLOPs per layer
+  and causal (query, key) pair, P(P+1)/2 pairs a prompt.  Attention
+  FLOPs of non-MLA configs are not counted (they stay on the
+  2·N_active rule); their KV bytes are.
 """
 from __future__ import annotations
 
@@ -62,24 +81,66 @@ def kv_bytes_per_token(cfg) -> float:
     return float(n_attn * per_layer * KV_BYTES_PER_EL)
 
 
+def experts_touched(n_experts: int, top_k: int, batch: int) -> float:
+    """Expected routed experts one MoE layer streams at decode batch B."""
+    return n_experts * (1.0 - (1.0 - top_k / n_experts) ** batch)
+
+
+def mla_attn_flops(cfg) -> tuple[float, float]:
+    """(decode FLOPs per token per context position, prefill FLOPs per
+    causal (query, key) pair), summed over the layers; (0, 0) for
+    configs without latent attention."""
+    m = cfg.mla
+    if m is None:
+        return 0.0, 0.0
+    two_h = 2.0 * cfg.n_heads
+    decode = two_h * (m.kv_lora + m.qk_rope) + two_h * m.kv_lora
+    prefill = two_h * (m.qk_nope + m.qk_rope) + two_h * m.v_dim
+    return cfg.n_layers * decode, cfg.n_layers * prefill
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelServingCost:
-    """Analytic serving cost of one config for one request shape."""
+    """Analytic serving cost of one config for one request shape.
+
+    The per-mechanism counts default to a dense, non-MLA model: no
+    routed experts and no attention FLOPs over the context.
+    """
     config: str
     request: RequestShape
     n_params: float             # total parameters
     n_active: float             # active per token (MoE top-k discount)
     kv_bytes_tok: float         # KV bytes per context token per sequence
+    routed_params_layer: float = 0.0    # routed-expert params, one layer
+    n_moe_layers: int = 0
+    n_experts: int = 0          # routed experts per MoE layer (E)
+    top_k: int = 0              # routed experts per token (k)
+    attn_decode_flops_ctx: float = 0.0  # per token per context position
+    attn_prefill_flops_pair: float = 0.0    # per causal (q, k) pair
 
     # ------------------------------------------------------------- flops
     @property
+    def attn_prefill_flops(self) -> float:
+        """Latent-attention FLOPs of one prompt: P(P+1)/2 causal pairs."""
+        p = self.request.prompt_tokens
+        return self.attn_prefill_flops_pair * p * (p + 1) / 2.0
+
+    @property
+    def attn_flops_per_token(self) -> float:
+        """Latent-attention FLOPs of one decode token at the mean
+        context."""
+        return self.attn_decode_flops_ctx * self.mean_context
+
+    @property
     def prefill_flops(self) -> float:
-        """2·N_active per prompt token (launch/roofline.py serving rule)."""
-        return 2.0 * self.n_active * self.request.prompt_tokens
+        """2·N_active per prompt token (launch/roofline.py serving rule)
+        plus the prompt's latent attention."""
+        return 2.0 * self.n_active * self.request.prompt_tokens \
+            + self.attn_prefill_flops
 
     @property
     def decode_flops_per_token(self) -> float:
-        return 2.0 * self.n_active
+        return 2.0 * self.n_active + self.attn_flops_per_token
 
     @property
     def request_flops(self) -> float:
@@ -90,8 +151,8 @@ class ModelServingCost:
     # ------------------------------------------------------------- bytes
     @property
     def param_bytes(self) -> float:
-        """Weight stream of one decode step (active parameters, read once
-        per step regardless of batch — the batching amortization)."""
+        """Weight stream of one decode step at batch 1 (active
+        parameters)."""
         return BYTES_PER_PARAM * self.n_active
 
     @property
@@ -99,13 +160,36 @@ class ModelServingCost:
         """Average live context length during decode."""
         return self.request.prompt_tokens + self.request.output_tokens / 2.0
 
+    def experts_touched(self, batch: int) -> float:
+        """Routed experts one MoE layer streams at decode batch B."""
+        if self.n_experts == 0:
+            return 0.0
+        return experts_touched(self.n_experts, self.top_k, batch)
+
+    def weight_bytes_per_step(self, batch: int) -> float:
+        """Weight stream of one decode step at batch B: everything but
+        the routed experts once, and each MoE layer's touched experts.
+
+        Written as the batch-1 active count plus the experts touched
+        beyond k, so batch 1 is `param_bytes` to the last bit."""
+        if batch < 1 or batch != int(batch):
+            raise ValueError("batch must be a whole number >= 1")
+        extra = 0.0
+        if self.n_experts:
+            miss = 1.0 - self.top_k / self.n_experts
+            extra = self.routed_params_layer * self.n_moe_layers \
+                * (miss - miss ** batch)
+        return BYTES_PER_PARAM * (self.n_active + extra)
+
+    def kv_bytes_per_step(self, batch: int) -> float:
+        """Per-sequence KV/state reads of one decode step at batch B."""
+        return batch * self.kv_bytes_tok * self.mean_context
+
     def decode_step_bytes(self, batch: int) -> float:
         """DRAM bytes of one decode step at batch size B: one shared
-        parameter read + per-sequence KV/state reads."""
-        if batch < 1:
-            raise ValueError("batch must be >= 1")
-        return self.param_bytes \
-            + batch * self.kv_bytes_tok * self.mean_context
+        weight read + per-sequence KV/state reads."""
+        return self.weight_bytes_per_step(batch) \
+            + self.kv_bytes_per_step(batch)
 
     def decode_ai(self, batch: int) -> float:
         """Decode arithmetic intensity at batch B [flop/word] — rises
@@ -130,27 +214,47 @@ class ModelServingCost:
 
 
 @functools.lru_cache(maxsize=None)
-def _params(config: str) -> tuple[float, float]:
+def _params(config: str) -> tuple[int, int, int]:
+    """(total, active, routed-expert) parameter counts of one config,
+    from its parameter shapes; the rest of the total (shared experts,
+    dense FFNs, attention, norms, embedding and head) is read once a
+    decode step."""
     import jax.numpy as jnp
+    import jax.tree_util as jtu
     from repro.configs import get_config
     from repro.launch import roofline as RF
     from repro.launch.steps import params_sds
 
     cfg = get_config(config)
     psds = params_sds(cfg, jnp.bfloat16)      # eval_shape only, no compile
-    return RF.count_params(psds), RF.count_active_params(cfg, psds)
+    routed = sum(
+        leaf.size for path, leaf in jtu.tree_leaves_with_path(psds)
+        if "experts" in [getattr(k, "key", getattr(k, "name", str(k)))
+                         for k in path])
+    return (RF.count_params(psds), RF.count_active_params(cfg, psds),
+            routed)
 
 
 def serving_cost(config: str,
                  request: RequestShape = RequestShape()) -> ModelServingCost:
     """Build the analytic serving cost for one registered config."""
     from repro.configs import get_config
-    n_total, n_active = _params(config)
+    cfg = get_config(config)
+    n_total, n_active, routed = _params(config)
+    moe = cfg.moe
+    n_moe = cfg.n_layers - moe.first_dense if moe is not None else 0
+    attn_decode, attn_prefill = mla_attn_flops(cfg)
     return ModelServingCost(
         config=config, request=request, n_params=float(n_total),
-        n_active=float(n_active),
-        kv_bytes_tok=kv_bytes_per_token(get_config(config)))
+        n_active=float(n_active), kv_bytes_tok=kv_bytes_per_token(cfg),
+        routed_params_layer=routed / n_moe if routed else 0.0,
+        n_moe_layers=n_moe if routed else 0,
+        n_experts=moe.n_routed if routed else 0,
+        top_k=moe.top_k if routed else 0,
+        attn_decode_flops_ctx=attn_decode,
+        attn_prefill_flops_pair=attn_prefill)
 
 
 __all__ = ["RequestShape", "ModelServingCost", "serving_cost",
-           "kv_bytes_per_token", "BYTES_PER_PARAM", "KV_BYTES_PER_EL"]
+           "kv_bytes_per_token", "experts_touched", "mla_attn_flops",
+           "BYTES_PER_PARAM", "KV_BYTES_PER_EL"]

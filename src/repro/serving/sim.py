@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -103,23 +104,32 @@ def fluid_queue(arrivals: np.ndarray, cost: ModelServingCost,
     effect is the number of requests in system clamped to ``max_batch``
     (continuous batching: every live sequence advances each step, the
     parameter read amortized across them — ``models/serve.py``
-    semantics).  Request latency = fluid FIFO finish time − arrival
-    time, floored by the request's serialized decode time at the batch
-    in effect (B·flops/token per generated token: batching trades
-    single-stream latency for shared-weight throughput).
+    semantics).  An interval's arrivals join the queue at its start and
+    the server works at ``throttle[t] * cap`` until the queue is empty.
+    Request latency = FIFO finish time (the first instant the served
+    work covers the request's own) − arrival time, floored by the
+    request's serialized decode time at the batch in effect
+    (B·flops/token per generated token: batching trades single-stream
+    latency for shared-weight throughput).  Requests still queued at
+    the horizon finish at the last interval's rate.
     """
     arrivals = np.asarray(arrivals)
     T = arrivals.shape[0]
     throttle = np.broadcast_to(np.asarray(throttle, np.float64), (T,))
     w_req = cost.request_flops
     cap_dt = cap_flops_per_s * interval_s
+    n_req = int(arrivals.sum())
 
     served = np.zeros(T)
     busy = np.zeros(T)
     batch = np.ones(T)
     backlog_end = np.zeros(T)
+    t_fin = np.empty(n_req)
     backlog = 0.0
+    arrived = done = 0          # requests in so far; requests finished
+    head_left = w_req           # work left of the oldest unfinished one
     for t in range(T):
+        arrived += int(arrivals[t])
         backlog += arrivals[t] * w_req
         avail = throttle[t] * cap_dt
         s = min(backlog, avail)
@@ -129,26 +139,27 @@ def fluid_queue(arrivals: np.ndarray, cost: ModelServingCost,
         backlog_end[t] = backlog
         n_live = backlog / w_req + arrivals[t]
         batch[t] = min(max_batch, max(1.0, math.ceil(n_live)))
-
-    # ---- per-request latency from cumulative arrived vs served work ----
-    n_req = int(arrivals.sum())
+        # FIFO finishes inside the interval; an emptied queue (backlog
+        # exactly 0) finishes every request in it
+        used = 0.0
+        while done < arrived and (backlog == 0.0 or used + head_left <= s):
+            used += head_left
+            t_fin[done] = (t + used / avail) * interval_s if avail > 0 \
+                else t * interval_s
+            done += 1
+            head_left = w_req
+        if done < arrived:
+            head_left -= s - used
     if n_req == 0:
         return QueueResult(served, busy, batch, backlog_end, np.zeros(0))
-    # arrival times: uniform within each interval; work positions: FIFO
+    # past the horizon the queue drains at the final capacity
+    tail_rate = max(throttle[-1] * cap_flops_per_s, 1e-6 * cap_flops_per_s)
+    left = head_left + np.arange(n_req - done) * w_req
+    t_fin[done:] = T * interval_s + left / tail_rate
+    # arrival times: uniform within each interval
     t_arr = np.repeat(np.arange(T) * interval_s, arrivals) \
         + np.concatenate([(np.arange(a) + 0.5) / max(a, 1) * interval_s
-                          for a in arrivals]) if n_req else np.zeros(0)
-    w_pos = (np.arange(n_req) + 1.0) * w_req     # finish needs own work done
-    S = np.concatenate([[0.0], np.cumsum(served)])
-    t_edge = np.arange(T + 1) * interval_s
-    # extrapolate past the horizon at the final capacity so every request
-    # finishes and the tail percentile stays meaningful under overload
-    tail_rate = max(throttle[-1] * cap_flops_per_s, 1e-6 * cap_flops_per_s)
-    extra = max(w_pos[-1] - S[-1], 0.0)
-    S_ext = np.concatenate([S, [S[-1] + extra + cap_dt]])
-    t_ext = np.concatenate([t_edge, [t_edge[-1]
-                                     + (extra + cap_dt) / tail_rate]])
-    t_fin = np.interp(w_pos, S_ext, t_ext)
+                          for a in arrivals])
     # serialized-decode floor at the batch in effect on arrival
     b_arr = np.repeat(batch, arrivals)
     floor = (cost.prefill_flops + cost.request.output_tokens
@@ -160,6 +171,16 @@ def fluid_queue(arrivals: np.ndarray, cost: ModelServingCost,
 # ---------------------------------------------------------------------------
 # reporting
 # ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class RoundRecord:
+    """What one throttle↔queue macro-round produced: its queue's
+    request latencies and its replay's coarse-interval record."""
+    latency_s: np.ndarray       # per-request latency [n_requests]
+    peak_C: np.ndarray          # [Tc, n_die] hottest die-layer cell
+    min_C: np.ndarray           # [Tc, n_die] coolest die-layer cell
+    throttle: np.ndarray        # [Tc] DTM performance duty
+
 
 @dataclasses.dataclass(frozen=True)
 class ServingReport:
@@ -177,6 +198,8 @@ class ServingReport:
     n_coarse: int
     error_bound_C: float        # coarsening bound: tol x DC gain
     throttle_residual: float    # max |f_k - f_{k-1}| of the last round
+    rounds: tuple[RoundRecord, ...] = ()    # every macro-round, in order
+    cost: ModelServingCost | None = None    # the price the rounds used
 
     @property
     def coarsen_ratio(self) -> float:
@@ -265,46 +288,65 @@ def _serving_round(scenario: ServingScenario, arrivals, cost, cap, dp,
 
     Returns ``(q, plan, f_base, residual, repl)`` with ``repl`` the full
     replay output ``(dyn, peaks, mins, picard_res, f_c, ref_W, leak_Wt,
-    dyn_Wt)`` of this round (``dyn`` kept for the coarsening error
-    bound).
+    dyn_Wt)`` of this round on the host (``dyn`` kept for the
+    coarsening error bound).
     """
     tr = scenario.traffic
     T = arrivals.shape[0]
-    q = fluid_queue(arrivals, cost, cap, f_base, tr.interval_s,
-                    scenario.max_batch)
-    # demand traffic at the interval's decode batch (per-batch AI)
-    traffic_t = np.array(
-        [q.busy[t] * cost.traffic_bytes_per_s(int(q.batch[t]),
-                                              dp.ap_n_pus)
-         for t in range(T)])
-    if plan is None:        # frozen after round 1: stable compile
-        if coarsen and scenario.coarsen_tol > 0:
-            tref = max(traffic_t.max(), 1e-30)
-            joint = np.stack([q.busy, traffic_t / tref], axis=1)
-            plan = cosim.coarsen_plan(joint, scenario.coarsen_tol,
-                                      scenario.max_merge)
-            qmax = scenario.pad_quantum
-            plan = plan.pad_to(
-                min(-(-plan.n_coarse // qmax) * qmax, T))
-        else:
-            plan = cosim.CoarsePlan(np.ones(T, np.int64))
-    busy_c = plan.merge(q.busy)
-    traffic_c = plan.merge(traffic_t)
-    dyn, l0, r0, lm = feedback.stack_power_frames(
-        spec, grid, busy_c, pmap, leak_W, dfp, traffic_c)
-    res = feedback.closed_loop_replay(
-        jnp.asarray(dyn), jnp.asarray(l0), jnp.asarray(r0),
-        jnp.asarray(lm), grid.fields(), grid.capacity_field(),
-        tr.interval_s, scenario.theta, fb=fb,
-        die_n=scenario.grid_n, n_die=spec.n_die_layers,
-        steps_per_interval=scenario.steps_per_interval,
-        n_cg=scenario.n_cg, margin=margin, solver="pcg",
-        dt_scale=jnp.asarray(plan.dt_scale()))
+    with obs.span("serving/queue", n_base=T):
+        q = fluid_queue(arrivals, cost, cap, f_base, tr.interval_s,
+                        scenario.max_batch)
+        # demand traffic at the interval's decode batch (per-batch AI)
+        traffic_t = np.array(
+            [q.busy[t] * cost.traffic_bytes_per_s(int(q.batch[t]),
+                                                  dp.ap_n_pus)
+             for t in range(T)])
+    with obs.span("serving/plan"):
+        if plan is None:        # frozen after round 1: stable compile
+            if coarsen and scenario.coarsen_tol > 0:
+                tref = max(traffic_t.max(), 1e-30)
+                joint = np.stack([q.busy, traffic_t / tref], axis=1)
+                plan = cosim.coarsen_plan(joint, scenario.coarsen_tol,
+                                          scenario.max_merge)
+                qmax = scenario.pad_quantum
+                plan = plan.pad_to(
+                    min(-(-plan.n_coarse // qmax) * qmax, T))
+            else:
+                plan = cosim.CoarsePlan(np.ones(T, np.int64))
+        busy_c = plan.merge(q.busy)
+        traffic_c = plan.merge(traffic_t)
+    with obs.span("serving/frames", n_coarse=plan.n_coarse):
+        dyn, l0, r0, lm = feedback.stack_power_frames(
+            spec, grid, busy_c, pmap, leak_W, dfp, traffic_c)
+    with obs.span("serving/replay", n_coarse=plan.n_coarse):
+        res = feedback.closed_loop_replay(
+            jnp.asarray(dyn), jnp.asarray(l0), jnp.asarray(r0),
+            jnp.asarray(lm), grid.fields(), grid.capacity_field(),
+            tr.interval_s, scenario.theta, fb=fb,
+            die_n=scenario.grid_n, n_die=spec.n_die_layers,
+            steps_per_interval=scenario.steps_per_interval,
+            n_cg=scenario.n_cg, margin=margin, solver="pcg",
+            dt_scale=jnp.asarray(plan.dt_scale()))
+    with obs.span("sync/replay"):
+        res = jax.device_get(res)
     _, peaks, mins, picard_res, f_c, ref_W, leak_Wt, dyn_Wt = res
-    f_new = plan.expand(np.asarray(f_c))
+    f_new = plan.expand(f_c)
     residual = float(np.abs(f_new - f_base).max())
     return q, plan, f_new, residual, (dyn, peaks, mins, picard_res, f_c,
                                       ref_W, leak_Wt, dyn_Wt)
+
+
+def _count_mechanisms(cost: ModelServingCost, batch: np.ndarray) -> None:
+    """Per-mechanism cost counters at each base interval's batch."""
+    steps = [int(b) for b in batch]
+    if cost.n_experts:
+        obs.observe_many("serving/experts_touched",
+                         [cost.experts_touched(b) for b in steps])
+    obs.observe_many("serving/weight_bytes_per_step",
+                     [cost.weight_bytes_per_step(b) for b in steps])
+    obs.observe_many("serving/kv_bytes_per_step",
+                     [cost.kv_bytes_per_step(b) for b in steps])
+    obs.observe("serving/attn_flops_per_token", cost.attn_flops_per_token)
 
 
 def run_serving_cosim(scenario: ServingScenario,
@@ -318,12 +360,13 @@ def run_serving_cosim(scenario: ServingScenario,
     every base interval uniformly (the reference the error bound is
     stated against; the property test diffs the two).
     """
-    cost = serving_cost(scenario.config, scenario.request)
-    # the machine pair: same-performance AP/SIMD at the serving AI of a
-    # saturated decode batch (the thermally-binding operating point)
-    wl = cost.workload(scenario.max_batch)
-    dp = cosim.comparable_design_point(wl)
-    cap = M.ap_flops_per_s(dp.ap_n_pus)
+    with obs.span("serving/cost", config=scenario.config):
+        cost = serving_cost(scenario.config, scenario.request)
+        # the machine pair: same-performance AP/SIMD at the serving AI of
+        # a saturated decode batch (the thermally-binding operating point)
+        wl = cost.workload(scenario.max_batch)
+        dp = cosim.comparable_design_point(wl)
+        cap = M.ap_flops_per_s(dp.ap_n_pus)
 
     tr = scenario.traffic
     mean_qps = tr.mean_qps if tr.mean_qps > 0 else \
@@ -345,6 +388,7 @@ def run_serving_cosim(scenario: ServingScenario,
         f_base = np.ones(T)
         plan = None
         residual = np.inf
+        rounds = []
         span = obs.span("serving/machine", machine=machine,
                         scenario=scenario.label, n_base=T)
         with span:
@@ -354,8 +398,11 @@ def run_serving_cosim(scenario: ServingScenario,
                         scenario, arrivals, cost, cap, dp, f_base, plan,
                         coarsen, spec, grid, pmap, leak_W, dfp, fb,
                         margin)
+                rounds.append(RoundRecord(q.latency_s, repl[1], repl[2],
+                                          repl[4]))
         dyn, peaks, mins, picard_res, f_c, ref_W, leak_Wt, dyn_Wt = repl
         if obs.is_enabled():
+            _count_mechanisms(cost, q.batch)
             w_req = cost.request_flops
             obs.count("serving/requests", q.latency_s.size)
             obs.count("serving/base_intervals", T)
@@ -381,7 +428,8 @@ def run_serving_cosim(scenario: ServingScenario,
             scenario=scenario, dp=dp, mean_qps=mean_qps, stack=stack_rep,
             durations_s=plan.dt_scale() * tr.interval_s, queue=q,
             latency_s=q.latency_s, n_base=T, n_coarse=plan.n_coarse,
-            error_bound_C=bound, throttle_residual=residual)
+            error_bound_C=bound, throttle_residual=residual,
+            rounds=tuple(rounds), cost=cost)
     return out
 
 
@@ -404,5 +452,5 @@ def verdict_table(reports: dict[str, dict[str, ServingReport]]) -> str:
     return "\n".join(lines)
 
 
-__all__ = ["ServingScenario", "ServingReport", "QueueResult",
+__all__ = ["ServingScenario", "ServingReport", "QueueResult", "RoundRecord",
            "fluid_queue", "run_serving_cosim", "verdict_table"]
