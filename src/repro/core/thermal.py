@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from collections import OrderedDict
 from functools import partial
 
 import jax
@@ -67,6 +68,13 @@ SOLVERS = ("pcg", "mg", "mgcg")
 #: diverged (non-finite) or genuinely stagnated solve, which is what
 #: the fallback chain catches.
 HEALTH_RTOL = 2e-2
+
+#: grids whose conductance fields :meth:`Grid.fields` keeps on the device.
+#: The sweep and serving paths make a new grid per job (``r_convec`` is
+#: drawn per job), so the cache must be bounded; a steady map on a fixed
+#: grid hits every time after its first solve.
+FIELDS_CACHE_SIZE = 4
+_FIELDS_CACHE: OrderedDict = OrderedDict()
 
 
 def package_resistance(die_area_m2: float, p: StackParams = PAPER_STACK
@@ -153,7 +161,30 @@ class Grid:
         the full domain.  Returns seven [L, NY, NX] arrays: gx_lf, gx_rt,
         gy_up, gy_dn (lateral faces), gz_up, gz_dn (interfaces), g_pkg
         (bottom lump).
+
+        The arrays are built once per (grid, default device) and kept on
+        the device in an LRU of :data:`FIELDS_CACHE_SIZE` grids; a hit
+        hands back the same arrays (``thermal/fields/cache_hit`` and
+        ``thermal/fields/cache_miss`` count the lookups).
         """
+        key = (self, jax.config.jax_default_device)
+        F = _FIELDS_CACHE.get(key)
+        if F is None:
+            obs.count("thermal/fields/cache_miss")
+            # concrete arrays even when called under a trace: a cached
+            # tracer would leak out of the trace that made it
+            with jax.ensure_compile_time_eval():
+                F = self._build_fields()
+            _FIELDS_CACHE[key] = F
+            if len(_FIELDS_CACHE) > FIELDS_CACHE_SIZE:
+                _FIELDS_CACHE.popitem(last=False)
+        else:
+            obs.count("thermal/fields/cache_hit")
+            _FIELDS_CACHE.move_to_end(key)
+        return dict(F)
+
+    def _build_fields(self) -> dict:
+        """:meth:`fields` built afresh, in numpy, and uploaded."""
         g = self.conductances()
         L = self.n_layers
         NY, NX, m = self.dom_ny, self.dom_nx, self.margin
@@ -445,27 +476,51 @@ def fallback_chain(solver: str) -> tuple[tuple[str, float], ...]:
     return tuple((s, 1.0) for s in tail) + (("pcg", 0.1),)
 
 
-def _solve_fields_guarded(b, F, solver: str, use_pallas: bool,
-                          tol: float = 1e-8):
+@partial(jax.jit, static_argnames=("n_layers", "margin"))
+def _steady_rhs(power, n_layers: int, margin: int):
+    """Die power [n_die, ny, nx] -> the solve's right-hand side [L, NY, NX]
+    (spreader and margin ring heatless), whether it is all finite, and
+    its norm."""
+    b = jnp.pad(power, ((0, n_layers - power.shape[0]),
+                        (margin, margin), (margin, margin)))
+    return b, jnp.isfinite(b).all(), jnp.linalg.norm(b)
+
+
+@partial(jax.jit, static_argnames=("window",))
+def _attempt_health(b, dT, F, finite, bnorm, iters, t_amb, window):
+    """One attempt's die temperatures and health, in one program.
+
+    ``window`` is ``(n_die, margin, ny, nx)``.  Returns ``(T, health)``:
+    ``T`` the die window of ``dT`` plus ``t_amb``, and ``health`` the
+    float32 vector ``[finite, ||b||, ||b - G dT|| / ||b||, iterations]``
+    the host fetches in one transfer.
+    """
+    n_die, m, ny, nx = window
+    rel = jnp.linalg.norm(b - apply_operator_fields(dT, F)) / bnorm
+    health = jnp.stack([finite.astype(jnp.float32), bnorm, rel,
+                        jnp.asarray(iters, jnp.float32)])
+    return dT[:n_die, m:m + ny, m:m + nx] + t_amb, health
+
+
+def _solve_fields_guarded(rhs, F, solver: str, use_pallas: bool,
+                          tol: float, window: tuple, t_amb: float):
     """:func:`_solve_fields` hardened by health checks + fallback.
 
-    After each attempt the TRUE relative residual ``||b - G x||/||b||``
-    is recomputed; a non-finite or ``> HEALTH_RTOL`` residual (a
-    diverged or stagnated solve — or a backend forced down by
+    ``rhs`` is :func:`_steady_rhs`'s ``(b, finite, ||b||)``.  Each
+    attempt dispatches its solve and :func:`_attempt_health`, then
+    fetches the health vector in one transfer (``sync/health``).  A
+    non-finite ``b`` raises ``ValueError``; a zero ``||b||`` (x = 0 is
+    exact) or a non-finite one (no backend can fix it) returns the
+    ambient after one attempt.  A non-finite or ``> HEALTH_RTOL`` TRUE
+    relative residual ``||b - G x||/||b||`` (a diverged or stagnated
+    solve — or a backend forced down by
     ``repro.faults.inject.poison_solver``) advances to the next rung of
-    :func:`fallback_chain`.  Returns ``(dT, iterations, stats)`` with
+    :func:`fallback_chain`.  Returns ``(T_die, iterations, stats)`` with
     ``stats = {"attempts", "solved_by", "rel_residual"}``; retries are
     counted in ``obs`` under ``thermal/fallback/*``.
     """
     from repro.faults import inject
-    with obs.span("sync/bnorm"):
-        bnorm = float(jnp.linalg.norm(b))
-    if bnorm == 0.0 or not math.isfinite(bnorm):
-        # zero RHS: x = 0 is exact.  A non-finite RHS no backend can fix
-        # — report it honestly rather than looping the chain.
-        resid = 0.0 if bnorm == 0.0 else math.inf
-        return jnp.zeros_like(b), 0, {"attempts": 1, "solved_by": solver,
-                                      "rel_residual": resid}
+    b, finite, bnorm = rhs
     last = None
     for i, (s, scale) in enumerate(fallback_chain(solver)):
         with obs.span("thermal/steady/solve", solver=s, attempt=i + 1):
@@ -473,14 +528,24 @@ def _solve_fields_guarded(b, F, solver: str, use_pallas: bool,
                 dT, iters = jnp.full_like(b, jnp.nan), 0
             else:
                 dT, iters = _solve_fields(b, F, s, use_pallas, tol * scale)
-        with obs.span("thermal/steady/residual"):
-            resid = jnp.linalg.norm(b - apply_operator_fields(dT, F)) / bnorm
-            with obs.span("sync/residual"):
-                resid = float(resid)
-        with obs.span("sync/iters"):
-            iters = int(iters)
-        last = (dT, iters, {"attempts": i + 1, "solved_by": s,
-                            "rel_residual": resid})
+        with obs.span("thermal/steady/check"):
+            T, health = _attempt_health(b, dT, F, finite, bnorm, iters,
+                                        t_amb, window=window)
+            with obs.span("sync/health"):
+                b_finite, b_norm, resid, n_iter = np.asarray(health).tolist()
+        # b's finiteness and norm come back with every attempt; the first
+        # attempt's fetch settles both
+        if not b_finite:
+            raise ValueError(
+                "steady_state: power map has non-finite cells; "
+                "refusing to solve — NaN temperatures would silently "
+                "poison every downstream verdict")
+        if b_norm == 0.0 or not math.isfinite(b_norm):
+            resid = 0.0 if b_norm == 0.0 else math.inf
+            return jnp.full_like(T, t_amb), 0, {
+                "attempts": 1, "solved_by": solver, "rel_residual": resid}
+        last = (T, int(n_iter), {"attempts": i + 1, "solved_by": s,
+                                 "rel_residual": resid})
         if math.isfinite(resid) and resid <= HEALTH_RTOL:
             if i:
                 obs.count("thermal/fallback/recovered")
@@ -509,37 +574,27 @@ def steady_state_stats(power: np.ndarray | jax.Array, grid: Grid,
     An unhealthy solve (non-finite or ``> HEALTH_RTOL`` residual)
     automatically retries down :func:`fallback_chain`; ``attempts`` and
     ``solved_by`` record how far it had to go (``solver`` stays the
-    REQUESTED backend).  Non-finite power maps raise ``ValueError`` up
-    front.
+    REQUESTED backend).  Non-finite power maps raise ``ValueError``
+    before anything is returned.
+
+    Each attempt runs device programs only — the padding, the solver's
+    own program and the health check — on the cached
+    :meth:`Grid.fields`, and blocks once, on its health vector.
     """
     with obs.span("thermal/steady", solver=solver,
                   shape=f"{grid.n_layers}x{grid.dom_ny}x{grid.dom_nx}"):
         with obs.span("thermal/steady/fields"):
             F = grid.fields()
-        with obs.span("thermal/steady/check_power"):
-            power = grid.pad_power(power)
-            with obs.span("sync/finite"):
-                finite = bool(jnp.isfinite(power).all())
-            if not finite:
-                raise ValueError(
-                    "steady_state: power map has non-finite cells; "
-                    "refusing to solve — NaN temperatures would silently "
-                    "poison every downstream verdict")
-            m = grid.margin
-            if m:
-                power = jnp.pad(power, ((0, 0), (m, m), (m, m)))
-        dT, iters, fstats = _solve_fields_guarded(power, F, solver,
-                                                  use_pallas, tol)
-        n_die = grid.n_die_layers
-        if m:
-            dT = dT[:n_die, m:m + grid.ny, m:m + grid.nx]
-        else:
-            dT = dT[:n_die]
+        with obs.span("thermal/steady/rhs"):
+            rhs = _steady_rhs(jnp.asarray(power, jnp.float32),
+                              grid.n_layers, grid.margin)
+        window = (grid.n_die_layers, grid.margin, grid.ny, grid.nx)
+        T, iters, fstats = _solve_fields_guarded(rhs, F, solver, use_pallas,
+                                                 tol, window, t_amb)
         stats = {"iterations": iters, "solver": solver,
                  "rel_residual": fstats["rel_residual"],
                  "attempts": fstats["attempts"],
                  "solved_by": fstats["solved_by"]}
-        T = dT + t_amb
     return T, stats
 
 

@@ -120,6 +120,30 @@ def test_mg_smoother_compiles(one_chip, level):
     assert "tpu_custom_call" in hlo
 
 
+def test_steady_host_programs_compile(one_chip):
+    """The programs around a 384^2 steady solve: the right-hand side's
+    padding, finiteness and norm, then one attempt's health check and
+    die window."""
+    grid = thermal.Grid(die_w=5e-3, ny=N_DIE, nx=N_DIE, margin=N_DIE // 4,
+                        spec=dram_on_logic(2))
+    power = jax.ShapeDtypeStruct((grid.n_die_layers, N_DIE, N_DIE),
+                                 jnp.float32, sharding=one_chip)
+    rhs = thermal._steady_rhs.lower(power, grid.n_layers, grid.margin)
+    b, finite, bnorm = (jax.ShapeDtypeStruct(o.shape, o.dtype,
+                                             sharding=one_chip)
+                        for o in rhs.out_info)
+    assert b.shape == (7, 384, 384) and finite.dtype == jnp.bool_
+    rhs.compile()
+    iters = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    health = thermal._attempt_health.lower(
+        b, b, _sds(grid.fields(), one_chip), finite, bnorm, iters, 45.0,
+        window=(grid.n_die_layers, grid.margin, N_DIE, N_DIE))
+    T, vec = health.out_info
+    assert T.shape == (grid.n_die_layers, N_DIE, N_DIE)
+    assert vec.shape == (4,) and vec.dtype == jnp.float32
+    health.compile()
+
+
 # ---------------------------------------------------------------- AP
 
 def test_ap_match_compiles(one_chip):
