@@ -50,7 +50,7 @@ from repro.stack.spec import PAPER_STACK, dram_on_logic
 
 GRID_N = 8
 N_INTERVALS = 16
-N_CG = 25
+INNER = thermal.InnerSolve("pcg", 25)
 T_END = 0.25
 
 #: the swept sensing regimes (None = perfect sensors, the reference)
@@ -103,7 +103,7 @@ def main(argv=None):
             fb = feedback.FeedbackParams(policy=pol, faults=fspec)
             reps = feedback.replay_cases(
                 cases, spec, fb, GRID_N, interval_dt,
-                steps_per_interval=1, n_cg=N_CG, margin=margin)
+                steps_per_interval=1, inner=INNER, margin=margin)
             for label, rep in reps.items():
                 results[(label, fname, pname)] = rep
     scenarios = [label for label, _ in cases]
@@ -152,7 +152,7 @@ def main(argv=None):
     fb = feedback.FeedbackParams(policy=policies["naive"])
     base, bump = (feedback.replay_cases(
         [(label, (d, l0, r0, lm, F, cap3))], spec, fb, GRID_N,
-        interval_dt, steps_per_interval=1, n_cg=N_CG,
+        interval_dt, steps_per_interval=1, inner=INNER,
         margin=margin)[label] for d in (dyn, spiked))
     delta = float(bump.dram_peak_C.max() - base.dram_peak_C.max())
     print(f"# power spike (2 intervals x3): sort/ap dram peak "

@@ -129,9 +129,9 @@ def cosim_section(rec: Recorder, grid_n: int, n_intervals: int,
     import numpy as np
     probe = np.zeros((1, grid_n, grid_n), np.float32)
     probe[0, grid_n // 2, grid_n // 2] = 0.5
-    _, pk, _ = thermal.transient_solve_implicit(probe, grid, t_end=t_end,
-                                                n_steps=n_imp, n_cg=40,
-                                                with_residuals=True)
+    _, pk, _ = thermal.transient_solve_implicit(
+        probe, grid, t_end=t_end, n_steps=n_imp,
+        inner=thermal.InnerSolve("pcg", 40), with_residuals=True)
     rec.add(transient_probe_peak_C=float(pk[-1].max()))
     print("workload,machine,layer,peak_max_C,peak_final_C,span_max_C,"
           "time_above_85C_s")

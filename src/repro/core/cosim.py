@@ -44,6 +44,7 @@ from repro.core import models as M
 from repro.core import thermal
 from repro.core.constants import DRAM_LIMIT_C
 from repro.core.floorplan import MM, APFloorplan, SIMDFloorplan
+from repro.core.thermal import InnerSolve
 
 
 # ---------------------------------------------------------------------------
@@ -343,21 +344,16 @@ def interval_forecaster(A, solve, logic_mask3, t_amb):
 # ---------------------------------------------------------------------------
 
 def _replay(frames, F, cap3, interval_dt, theta, t_amb, *,
-            steps_per_interval: int, n_cg: int, n_si: int, margin: int,
-            die_n: int, use_pallas: bool):
-    if use_pallas:
-        from repro.kernels.thermal_stencil import ops as _ops
-        A = lambda v: _ops.apply_operator_fields(v, F)
-    else:
-        A = lambda v: thermal.apply_operator_fields(v, F)
-    dt = interval_dt / steps_per_interval
-    lhs = lambda v: cap3 / dt * v + theta * A(v)
-    Minv = 1.0 / (cap3 / dt + theta * thermal._diag_fields(F))
+            steps_per_interval: int, inner: InnerSolve, n_si: int,
+            margin: int, die_n: int):
+    A = thermal.fields_operator(F, inner.pallas)
+    solve = thermal.implicit_lhs_solver(A, F, cap3, theta, inner)(
+        interval_dt / steps_per_interval)
 
     def interval(dTc, P):
         def one(d, _):
             rhs = P - A(d)
-            return d + thermal.pcg_fixed(lhs, Minv, rhs, n_cg), None
+            return d + solve(rhs), None
         dTn, _ = jax.lax.scan(one, dTc, None, length=steps_per_interval)
         die = dTn[:n_si, margin:margin + die_n, margin:margin + die_n]
         return dTn, (jnp.max(die, axis=(1, 2)), jnp.min(die, axis=(1, 2)))
@@ -367,38 +363,36 @@ def _replay(frames, F, cap3, interval_dt, theta, t_amb, *,
     return dT_end + t_amb, mx + t_amb, mn + t_amb
 
 
-@partial(jax.jit, static_argnames=("steps_per_interval", "n_cg", "n_si",
-                                   "margin", "die_n", "use_pallas"))
+@partial(jax.jit, static_argnames=("steps_per_interval", "inner", "n_si",
+                                   "margin", "die_n"))
 def cosim_transient(frames, F: dict, cap3, interval_dt,
                     theta: float = 1.0, t_amb: float = thermal.AMBIENT_C, *,
-                    die_n: int, steps_per_interval: int = 2, n_cg: int = 40,
-                    n_si: int = 4, margin: int = 0,
-                    use_pallas: bool = False):
+                    die_n: int, steps_per_interval: int = 2,
+                    inner: InnerSolve = InnerSolve("pcg", 40),
+                    n_si: int = 4, margin: int = 0):
     """Replay one frame stack.  Returns (T_end [L,NY,NX],
     peak_C [T,n_si], min_C [T,n_si]) — peaks/mins over the die footprint
     of the silicon layers only."""
     return _replay(frames, F, cap3, interval_dt, theta, t_amb,
-                   steps_per_interval=steps_per_interval, n_cg=n_cg,
-                   n_si=n_si, margin=margin, die_n=die_n,
-                   use_pallas=use_pallas)
+                   steps_per_interval=steps_per_interval, inner=inner,
+                   n_si=n_si, margin=margin, die_n=die_n)
 
 
-@partial(jax.jit, static_argnames=("steps_per_interval", "n_cg", "n_si",
-                                   "margin", "die_n", "use_pallas"))
+@partial(jax.jit, static_argnames=("steps_per_interval", "inner", "n_si",
+                                   "margin", "die_n"))
 def cosim_transient_batch(frames, F: dict, cap3, interval_dt,
                           theta: float = 1.0,
                           t_amb: float = thermal.AMBIENT_C, *,
                           die_n: int, steps_per_interval: int = 2,
-                          n_cg: int = 40, n_si: int = 4, margin: int = 0,
-                          use_pallas: bool = False):
+                          inner: InnerSolve = InnerSolve("pcg", 40),
+                          n_si: int = 4, margin: int = 0):
     """vmapped replay over a leading batch of design points.
 
     frames [B,T,L,NY,NX]; each leaf of F and cap3 batched [B,...] (the
     batch shares one grid shape; conductances/capacities differ per die).
     """
-    fn = partial(_replay, steps_per_interval=steps_per_interval, n_cg=n_cg,
-                 n_si=n_si, margin=margin, die_n=die_n,
-                 use_pallas=use_pallas)
+    fn = partial(_replay, steps_per_interval=steps_per_interval,
+                 inner=inner, n_si=n_si, margin=margin, die_n=die_n)
     return jax.vmap(lambda fr, Fb, cb: fn(fr, Fb, cb, interval_dt, theta,
                                           t_amb))(frames, F, cap3)
 
@@ -476,9 +470,10 @@ def comparable_design_point(workload: str | M.Workload,
 
 def run_cosim(workloads=("dmm", "fft"), grid_n: int = 32,
               n_intervals: int = 64, t_end: float = 0.25,
-              steps_per_interval: int = 2, n_cg: int = 40,
-              theta: float = 1.0, stack: thermal.StackParams | None = None,
-              use_pallas: bool = False) -> dict:
+              steps_per_interval: int = 2,
+              inner: InnerSolve = InnerSolve("pcg", 40),
+              theta: float = 1.0,
+              stack: thermal.StackParams | None = None) -> dict:
     """The §4 comparison, transient: for each workload, replay the AP's
     measured trace and the SIMD reference's analytic trace through the
     same stack in ONE vmapped batch.  Returns
@@ -518,9 +513,8 @@ def run_cosim(workloads=("dmm", "fft"), grid_n: int = 32,
     capb = jnp.stack(all_cap)
     _, peaks, mins = cosim_transient_batch(
         frames, Fb, capb, interval_dt, theta,
-        steps_per_interval=steps_per_interval, n_cg=n_cg,
-        n_si=stack.n_si_layers, margin=margin, die_n=grid_n,
-        use_pallas=use_pallas)
+        steps_per_interval=steps_per_interval, inner=inner,
+        n_si=stack.n_si_layers, margin=margin, die_n=grid_n)
     peaks = np.asarray(peaks)
     mins = np.asarray(mins)
 

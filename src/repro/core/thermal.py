@@ -53,6 +53,7 @@ __all__ = [  # re-exports kept for callers of the pre-refactor module
     "apply_operator", "apply_operator_fields", "pcg", "pcg_fixed",
     "transient", "transient_solve", "explicit_dt", "transient_implicit",
     "transient_implicit_fields", "transient_solve_implicit",
+    "InnerSolve", "fields_operator", "implicit_lhs_solver",
 ]
 
 #: selectable linear-solver backends for the fields operator: Jacobi-PCG
@@ -383,13 +384,6 @@ def pcg_fixed(A, Minv, b, n_iter: int):
     return x
 
 
-@partial(jax.jit, static_argnames=("max_iter",))
-def _cg_solve(b, diag, g_lat, g_vert, g_pkg, tol=1e-8, max_iter=6000):
-    """Jacobi-preconditioned conjugate gradient for G T = b."""
-    A = lambda v: apply_operator(v, g_lat, g_vert, g_pkg)
-    return pcg(A, 1.0 / diag, b, tol, max_iter)[0]
-
-
 # ---------------------------------------------------------------------------
 # heterogeneous (face-conductance-field) operator — the production solver
 # ---------------------------------------------------------------------------
@@ -686,51 +680,98 @@ def _implicit_scan(dT0, power, A, solve, n_steps: int, lhs=None):
     return jax.lax.scan(step, dT0, None, length=n_steps)
 
 
-def implicit_lhs_solver(A, F, cap3, dt, theta, *, solver: str = "pcg",
-                        n_cg: int = 50, n_mg: int = 3,
-                        use_pallas: bool = False):
-    """Fixed-cost solve closure for the theta-scheme LHS
+#: inner-solve kinds of one theta-scheme step (:class:`InnerSolve`)
+INNER_KINDS = ("pcg", "mg")
+
+
+@dataclasses.dataclass(frozen=True)
+class InnerSolve:
+    """The fixed-cost inner solve of one theta-scheme step (hashable, so
+    it is a jit static argument).
+
+    ``kind`` "pcg": ``iters`` Jacobi-PCG iterations; "mg": ``iters``
+    multigrid V-cycles.  ``pallas`` runs the Pallas stencil matvec
+    (``kernels/thermal_stencil``) and the Pallas red-black line smoother
+    (``kernels/mg_smooth``) in place of their jnp references.
+    """
+    kind: str
+    iters: int
+    pallas: bool = False
+
+    def __post_init__(self):
+        if self.kind not in INNER_KINDS:
+            raise ValueError(f"unknown inner solve {self.kind!r}; "
+                             f"expected one of {INNER_KINDS}")
+        if self.iters < 1:
+            raise ValueError(f"iters must be >= 1; got {self.iters!r}")
+
+
+def fields_operator(F: dict, pallas: bool = False):
+    """The matvec closure ``v -> G v`` over the fields ``F``: the jnp
+    :func:`apply_operator_fields` or its Pallas kernel."""
+    if pallas:
+        from repro.kernels.thermal_stencil import ops as _ops
+        return lambda v: _ops.apply_operator_fields(v, F)
+    return lambda v: apply_operator_fields(v, F)
+
+
+def implicit_lhs_solver(A, F, cap3, theta, inner: InnerSolve):
+    """Fixed-cost solves of the theta-scheme LHS
     ``(C/dt + theta G) delta = rhs`` over the fields operator.
 
-    "pcg": ``n_cg`` Jacobi-PCG iterations on the closure ``A`` (which may
-    be the Pallas stencil).  "mg": ``n_mg`` V-cycles on the Galerkin
-    hierarchy of the theta-scaled fields — built ONCE here, outside any
-    scan, so coarse operators are constants of the compiled step.
+    Returns ``make(dt)``, which gives the solve closure for one step size.
+    "pcg": ``inner.iters`` Jacobi-PCG iterations on the closure ``A``; the
+    diagonal of G is computed once, here, so ``make`` may take a traced
+    ``dt`` inside a scan (the variable-dt replay).  "mg": ``inner.iters``
+    V-cycles on the Galerkin hierarchy of the theta-scaled fields, which
+    ``make(dt)`` builds for that ``dt`` — call it outside any scan, so
+    coarse operators are constants of the compiled step.
     """
-    lhs = lambda v: cap3 / dt * v + theta * A(v)
-    if solver == "mg":
+    if inner.kind == "mg":
         from repro.core import multigrid
-        F_lhs = {k: theta * v for k, v in F.items()}
-        levels = multigrid.build_levels(F_lhs, cap3 / dt)
-        sweep_fn = multigrid._resolve_sweep(use_pallas)
-        coarse = multigrid.coarse_solve_fn(levels)
-        return lambda rhs: multigrid.iterate_fixed(
-            levels, rhs, n_mg, sweep_fn=sweep_fn, coarse_solve=coarse)
-    if solver != "pcg":
-        raise ValueError(f"unknown solver {solver!r}; expected "
-                         f"('pcg', 'mg')")
-    Minv = 1.0 / (cap3 / dt + theta * _diag_fields(F))
-    return lambda rhs: pcg_fixed(lhs, Minv, rhs, n_cg)
+
+        def make(dt):
+            F_lhs = {k: theta * v for k, v in F.items()}
+            levels = multigrid.build_levels(F_lhs, cap3 / dt)
+            sweep_fn = multigrid._resolve_sweep(inner.pallas)
+            coarse = multigrid.coarse_solve_fn(levels)
+            return lambda rhs: multigrid.iterate_fixed(
+                levels, rhs, inner.iters, sweep_fn=sweep_fn,
+                coarse_solve=coarse)
+        return make
+    diag = _diag_fields(F)
+
+    def make(dt):
+        lhs = lambda v: cap3 / dt * v + theta * A(v)
+        Minv = 1.0 / (cap3 / dt + theta * diag)
+        return lambda rhs: pcg_fixed(lhs, Minv, rhs, inner.iters)
+    return make
 
 
-@partial(jax.jit, static_argnames=("n_steps", "n_cg", "with_residuals"))
+@partial(jax.jit, static_argnames=("n_steps", "inner", "with_residuals"))
 def transient_implicit(T0, power, g_lat, g_vert, g_pkg, cap, dt,
                        n_steps: int, theta: float = 1.0,
-                       t_amb: float = AMBIENT_C, n_cg: int = 50,
+                       t_amb: float = AMBIENT_C,
+                       inner: InnerSolve = InnerSolve("pcg", 50),
                        with_residuals: bool = False):
-    """Implicit counterpart of :func:`transient` (same contract/returns).
+    """Implicit counterpart of :func:`transient` (same contract/returns)
+    on the uniform operator: ``inner.iters`` Jacobi-PCG iterations a
+    step, on the jnp stencil (``inner.kind`` must be "pcg").
 
     ``with_residuals=True`` (static) appends per-step relative linear
     residuals to the return — ``(T, peaks, res)`` — for telemetry; the
     default keeps the historical 2-tuple and compiled program.
     """
+    if inner.kind != "pcg":
+        raise ValueError("transient_implicit runs PCG only; got "
+                         f"{inner.kind!r}")
     L = T0.shape[0]
     diag = _diag(T0.shape, g_lat, g_vert, g_pkg)
     cap3 = jnp.broadcast_to(jnp.asarray(cap, jnp.float32), (L,))[:, None, None]
     A = lambda v: apply_operator(v, g_lat, g_vert, g_pkg)
     lhs = lambda v: cap3 / dt * v + theta * A(v)
     Minv = 1.0 / (cap3 / dt + theta * diag)
-    solve = lambda rhs: pcg_fixed(lhs, Minv, rhs, n_cg)
+    solve = lambda rhs: pcg_fixed(lhs, Minv, rhs, inner.iters)
     if with_residuals:
         dT, (peaks, res) = _implicit_scan(T0 - t_amb, power, A, solve,
                                           n_steps, lhs=lhs)
@@ -739,26 +780,21 @@ def transient_implicit(T0, power, g_lat, g_vert, g_pkg, cap, dt,
     return dT + t_amb, peaks + t_amb
 
 
-@partial(jax.jit, static_argnames=("n_steps", "n_cg", "solver", "n_mg",
-                                   "use_pallas", "with_residuals"))
+@partial(jax.jit, static_argnames=("n_steps", "inner", "with_residuals"))
 def transient_implicit_fields(T0, power, F: dict, cap3, dt, n_steps: int,
                               theta: float = 1.0, t_amb: float = AMBIENT_C,
-                              n_cg: int = 50, solver: str = "pcg",
-                              n_mg: int = 3, use_pallas: bool = False,
+                              inner: InnerSolve = InnerSolve("pcg", 50),
                               with_residuals: bool = False):
     """Implicit theta-scheme on the heterogeneous (production) operator.
 
     T0/power: [L, NY, NX] over the full (die + margin) domain; cap3 the
-    per-cell capacity field (``Grid.capacity_field()``).  ``solver``
-    selects the fixed-cost inner solve: ``n_cg`` PCG iterations or
-    ``n_mg`` multigrid V-cycles per step.  ``with_residuals=True``
+    per-cell capacity field (``Grid.capacity_field()``).  ``inner`` is
+    the fixed-cost inner solve of each step.  ``with_residuals=True``
     (static) appends per-step relative linear residuals:
     ``(T, peaks, res)``.
     """
-    A = lambda v: apply_operator_fields(v, F)
-    solve = implicit_lhs_solver(A, F, cap3, dt, theta, solver=solver,
-                                n_cg=n_cg, n_mg=n_mg,
-                                use_pallas=use_pallas)
+    A = fields_operator(F, inner.pallas)
+    solve = implicit_lhs_solver(A, F, cap3, theta, inner)(dt)
     if with_residuals:
         lhs = lambda v: cap3 / dt * v + theta * A(v)
         dT, (peaks, res) = _implicit_scan(T0 - t_amb, power, A, solve,
@@ -770,13 +806,14 @@ def transient_implicit_fields(T0, power, F: dict, cap3, dt, n_steps: int,
 
 def transient_solve_implicit(power, grid: Grid, t_end: float,
                              n_steps: int, theta: float = 1.0,
-                             t_amb: float = AMBIENT_C, n_cg: int = 50,
-                             solver: str = "pcg", n_mg: int = 3,
+                             t_amb: float = AMBIENT_C,
+                             inner: InnerSolve = InnerSolve("pcg", 50),
                              with_residuals: bool = False):
     """Implicit counterpart of :func:`transient_solve` with a chosen step
     count (the point: n_steps can be 10-1000x below the explicit bound).
-    ``solver="mg"`` runs the multigrid inner solve on the fields form of
-    the same stack.
+    ``inner.kind == "mg"`` runs the multigrid inner solve on the fields
+    form of the same stack; "pcg" runs ``inner.iters`` PCG iterations on
+    the uniform operator (:func:`transient_implicit`).
 
     Returns ``(T, peaks)``.  ``with_residuals=True`` also computes the
     per-step relative inner-solve residuals on device (one extra matvec
@@ -787,26 +824,25 @@ def transient_solve_implicit(power, grid: Grid, t_end: float,
     power = grid.pad_power(power)
     dt = t_end / n_steps
     T0 = jnp.full(power.shape, t_amb, jnp.float32)
-    with obs.span("thermal/transient", solver=solver, n_steps=n_steps):
-        if solver == "mg":
+    with obs.span("thermal/transient", solver=inner.kind, n_steps=n_steps):
+        if inner.kind == "mg":
             F = grid.fields()
             cap3 = grid.capacity_field()
             out = transient_implicit_fields(T0, power, F, cap3, dt,
-                                            n_steps, theta, t_amb, n_cg,
-                                            solver="mg", n_mg=n_mg,
+                                            n_steps, theta, t_amb, inner,
                                             with_residuals=with_residuals)
         else:
             g = grid.conductances()
             cap = grid.capacities()
             out = transient_implicit(T0, power, g["g_lat"], g["g_vert"],
                                      g["g_pkg"], cap, dt, n_steps, theta,
-                                     t_amb, n_cg,
+                                     t_amb, inner,
                                      with_residuals=with_residuals)
     if with_residuals and obs.is_enabled():
         obs.count("thermal/transient/solves")
         obs.count("thermal/transient/steps", n_steps)
         obs.count("thermal/transient/inner_iterations",
-                  n_steps * (n_mg if solver == "mg" else n_cg))
+                  n_steps * inner.iters)
         obs.observe_many("thermal/transient/step_rel_residual",
                          np.asarray(out[2], np.float64))
     return out

@@ -1,1 +1,1 @@
-from repro.kernels.thermal_stencil.ops import apply_operator, cg_solve  # noqa: F401
+from repro.kernels.thermal_stencil.ops import apply_operator  # noqa: F401

@@ -1,8 +1,8 @@
 """Jitted wrappers: Pallas thermal stencil + CG solve built on it.
 
-``cg_solve`` mirrors :func:`repro.core.thermal._cg_solve` (Jacobi-
-preconditioned CG) with the stencil application replaced by the Pallas
-kernel; ``repro.core.thermal.steady_state(use_pallas=True)`` routes here.
+``cg_solve_fields_stats`` is Jacobi-preconditioned CG with the stencil
+application replaced by the Pallas kernel;
+``repro.core.thermal.steady_state(use_pallas=True)`` routes here.
 Conductances may be scalars or per-layer vectors (see core.thermal).
 """
 from __future__ import annotations
@@ -48,27 +48,4 @@ def cg_solve_fields_stats(b: jax.Array, F: dict, tol: float = 1e-8,
     A = lambda v: apply_operator_fields(v, F, block_y=block_y,
                                         interpret=interpret)
     return pcg(A, 1.0 / _diag_fields(F), b, tol, max_iter)
-
-
-def cg_solve_fields(b: jax.Array, F: dict, tol: float = 1e-8,
-                    max_iter: int = 8000, block_y: int = 32,
-                    interpret: bool | None = None) -> jax.Array:
-    return cg_solve_fields_stats(b, F, tol, max_iter, block_y,
-                                 interpret)[0]
-
-
-@functools.partial(jax.jit, static_argnames=("max_iter", "block_y",
-                                             "interpret"))
-def cg_solve(b: jax.Array, diag: jax.Array, g_lat, g_vert, g_pkg,
-             tol: float = 1e-8, max_iter: int = 6000,
-             block_y: int = 32, interpret: bool | None = None) -> jax.Array:
-    """Jacobi-preconditioned CG for G T = b with the Pallas stencil."""
-    from repro.core.thermal import pcg
-    L = b.shape[0]
-    g_lat, gv_u, gv_d, g_pkg_vec = _vectors(L, g_lat, g_vert, g_pkg)
-    A = lambda v: _kernel.apply_operator_kernel(
-        v, g_lat, gv_u, gv_d, g_pkg_vec, block_y=block_y,
-        interpret=interpret)
-    return pcg(A, 1.0 / diag, b, tol, max_iter)[0]
-
 

@@ -325,7 +325,7 @@ def _serving_round(scenario: ServingScenario, arrivals, cost, cap, dp,
             tr.interval_s, scenario.theta, fb=fb,
             die_n=scenario.grid_n, n_die=spec.n_die_layers,
             steps_per_interval=scenario.steps_per_interval,
-            n_cg=scenario.n_cg, margin=margin, solver="pcg",
+            inner=thermal.InnerSolve("pcg", scenario.n_cg), margin=margin,
             dt_scale=jnp.asarray(plan.dt_scale()))
     with obs.span("sync/replay"):
         res = jax.device_get(res)

@@ -55,6 +55,7 @@ from repro.core import models as M
 from repro.core import thermal
 from repro.core.constants import AMBIENT_C, DRAM_LIMIT_C
 from repro.core.floorplan import MM, APFloorplan, SIMDFloorplan
+from repro.core.thermal import InnerSolve
 from repro.faults.models import SensorFaultSpec
 from repro.policy import Policy, PolicyContext, RampPolicy
 from repro.stack import dram
@@ -124,39 +125,24 @@ class FeedbackParams:
 
 def _closed_loop(dyn_frames, leak0, refresh0, logic_mask, F, cap3,
                  interval_dt, theta, t_amb, *, fb: FeedbackParams,
-                 steps_per_interval: int, n_cg: int, n_die: int,
-                 margin: int, die_n: int, use_pallas: bool,
-                 solver: str = "pcg", n_mg: int = 3, dt_scale=None):
-    if use_pallas:
-        from repro.kernels.thermal_stencil import ops as _ops
-        A = lambda v: _ops.apply_operator_fields(v, F)
-    else:
-        A = lambda v: thermal.apply_operator_fields(v, F)
+                 steps_per_interval: int, inner: InnerSolve,
+                 n_die: int, margin: int, die_n: int, dt_scale=None):
+    A = thermal.fields_operator(F, inner.pallas)
+    make = thermal.implicit_lhs_solver(A, F, cap3, theta, inner)
     if dt_scale is None:
-        dt = interval_dt / steps_per_interval
-        # fixed-cost inner solve for the theta-scheme LHS: n_cg PCG
-        # iterations or n_mg multigrid V-cycles (hierarchy built once,
-        # here)
-        solve = thermal.implicit_lhs_solver(A, F, cap3, dt, theta,
-                                            solver=solver, n_cg=n_cg,
-                                            n_mg=n_mg, use_pallas=use_pallas)
+        solve = make(interval_dt / steps_per_interval)
         solve_for = lambda _scale: solve
     else:
         # variable-dt replay (coarsened serving traces): the step size is
-        # a traced per-interval quantity, so the theta-scheme LHS and its
-        # Jacobi preconditioner are rebuilt inside the scan body.  The
-        # multigrid hierarchy is assembled for ONE dt, hence PCG only.
-        if solver != "pcg":
+        # a traced per-interval quantity, so the solve is made inside the
+        # scan body.  The multigrid hierarchy is assembled for ONE dt,
+        # hence PCG only.
+        if inner.kind != "pcg":
             raise ValueError("variable-dt replay (dt_scale) requires "
                              "solver='pcg'; the multigrid hierarchy is "
                              "built for a fixed step")
-        diagA = thermal._diag_fields(F)
-
-        def solve_for(scale):
-            dt = interval_dt * scale / steps_per_interval
-            lhs = lambda v: cap3 / dt * v + theta * A(v)
-            Minv = 1.0 / (cap3 / dt + theta * diagA)
-            return lambda rhs: thermal.pcg_fixed(lhs, Minv, rhs, n_cg)
+        solve_for = lambda scale: make(
+            interval_dt * scale / steps_per_interval)
     lm3 = logic_mask[:, None, None]
     # DRAM layers are exactly the refresh-bearing ones (base refresh is
     # strictly positive on every DRAM die) — derived here so per-die
@@ -239,8 +225,7 @@ def _closed_loop(dyn_frames, leak0, refresh0, logic_mask, F, cap3,
             leak_W, dyn_W)
 
 
-_STATIC = ("fb", "steps_per_interval", "n_cg", "n_die", "margin", "die_n",
-           "use_pallas", "solver", "n_mg")
+_STATIC = ("fb", "steps_per_interval", "inner", "n_die", "margin", "die_n")
 
 
 @partial(jax.jit, static_argnames=_STATIC)
@@ -248,17 +233,16 @@ def closed_loop_replay(dyn_frames, leak0, refresh0, logic_mask, F: dict,
                        cap3, interval_dt, theta: float = 1.0,
                        t_amb: float = AMBIENT_C, *, fb: FeedbackParams,
                        die_n: int, n_die: int, steps_per_interval: int = 2,
-                       n_cg: int = 40, margin: int = 0,
-                       use_pallas: bool = False, solver: str = "pcg",
-                       n_mg: int = 3, dt_scale=None):
+                       inner: InnerSolve = InnerSolve("pcg", 40),
+                       margin: int = 0, dt_scale=None):
     """Replay one frame stack with temperature feedback.
 
     dyn_frames [T, L, NY, NX]: trace-modulated *dynamic* power (logic
     switching + DRAM activate/IO) — NO leakage or refresh baked in;
     leak0 / refresh0 [L, NY, NX]: leakage at ``fb.t_ref_C`` and 1× refresh
     power; logic_mask [L]: 1.0 on layers whose hot spot trips the DTM.
-    ``solver`` picks the fixed-cost inner solve: ``n_cg`` PCG iterations
-    ("pcg") or ``n_mg`` multigrid V-cycles ("mg").
+    ``inner`` is the fixed-cost inner solve of each implicit step
+    (``thermal.InnerSolve``).
 
     ``dt_scale`` [T] (optional) stretches interval i to
     ``interval_dt * dt_scale[i]`` — the variable-step replay coarsened
@@ -276,9 +260,8 @@ def closed_loop_replay(dyn_frames, leak0, refresh0, logic_mask, F: dict,
     """
     return _closed_loop(dyn_frames, leak0, refresh0, logic_mask, F, cap3,
                         interval_dt, theta, t_amb, fb=fb,
-                        steps_per_interval=steps_per_interval, n_cg=n_cg,
+                        steps_per_interval=steps_per_interval, inner=inner,
                         n_die=n_die, margin=margin, die_n=die_n,
-                        use_pallas=use_pallas, solver=solver, n_mg=n_mg,
                         dt_scale=dt_scale)
 
 
@@ -287,14 +270,12 @@ def closed_loop_batch(dyn_frames, leak0, refresh0, logic_mask, F: dict,
                       cap3, interval_dt, theta: float = 1.0,
                       t_amb: float = AMBIENT_C, *, fb: FeedbackParams,
                       die_n: int, n_die: int, steps_per_interval: int = 2,
-                      n_cg: int = 40, margin: int = 0,
-                      use_pallas: bool = False, solver: str = "pcg",
-                      n_mg: int = 3):
+                      inner: InnerSolve = InnerSolve("pcg", 40),
+                      margin: int = 0):
     """vmapped closed-loop replay over a leading design-point batch."""
     fn = partial(_closed_loop, fb=fb,
-                 steps_per_interval=steps_per_interval, n_cg=n_cg,
-                 n_die=n_die, margin=margin, die_n=die_n,
-                 use_pallas=use_pallas, solver=solver, n_mg=n_mg)
+                 steps_per_interval=steps_per_interval, inner=inner,
+                 n_die=n_die, margin=margin, die_n=die_n)
     return jax.vmap(
         lambda fr, l0, r0, lm, Fb, cb: fn(fr, l0, r0, lm, Fb, cb,
                                           interval_dt, theta, t_amb)
@@ -552,10 +533,9 @@ def closed_loop_sharded(dyn_frames, leak0, refresh0, logic_mask, F: dict,
                         cap3, interval_dt, theta: float = 1.0,
                         t_amb: float = AMBIENT_C, *, fb: FeedbackParams,
                         die_n: int, n_die: int,
-                        steps_per_interval: int = 2, n_cg: int = 40,
-                        margin: int = 0, use_pallas: bool = False,
-                        solver: str = "pcg", n_mg: int = 3,
-                        n_shards: int | None = None):
+                        steps_per_interval: int = 2,
+                        inner: InnerSolve = InnerSolve("pcg", 40),
+                        margin: int = 0, n_shards: int | None = None):
     """:func:`closed_loop_batch` partitioned over local devices.
 
     The case batch is padded to a multiple of the mesh size (repeating
@@ -575,8 +555,7 @@ def closed_loop_sharded(dyn_frames, leak0, refresh0, logic_mask, F: dict,
         return closed_loop_batch(
             *tree, interval_dt, theta, t_amb, fb=fb, die_n=die_n,
             n_die=n_die, steps_per_interval=steps_per_interval,
-            n_cg=n_cg, margin=margin, use_pallas=use_pallas,
-            solver=solver, n_mg=n_mg)
+            inner=inner, margin=margin)
 
     out = shardlib.shard_case_batch(fn, mesh)(batch)
     return shardlib.unpad_case_batch(out, n_cases)
@@ -584,9 +563,9 @@ def closed_loop_sharded(dyn_frames, leak0, refresh0, logic_mask, F: dict,
 
 def replay_cases(cases, spec: StackSpec, fb: FeedbackParams, grid_n: int,
                  interval_dt: float, *, theta: float = 1.0,
-                 steps_per_interval: int = 2, n_cg: int = 40,
-                 margin: int | None = None, use_pallas: bool = False,
-                 solver: str = "pcg", n_mg: int = 3,
+                 steps_per_interval: int = 2,
+                 inner: InnerSolve = InnerSolve("pcg", 40),
+                 margin: int | None = None,
                  n_shards: int | None = None) -> dict[str, "StackReport"]:
     """Replay pre-assembled cases as ONE vmapped closed-loop batch.
 
@@ -604,14 +583,13 @@ def replay_cases(cases, spec: StackSpec, fb: FeedbackParams, grid_n: int,
     replay = closed_loop_batch if not n_shards else partial(
         closed_loop_sharded, n_shards=n_shards)
     with obs.span("feedback/replay", cases=len(labels), grid_n=grid_n,
-                  solver=solver, n_shards=n_shards or 0):
+                  solver=inner.kind, n_shards=n_shards or 0):
         _, peaks, mins, res, thr, ref_W, leak_W, dyn_W = replay(
             jnp.asarray(np.stack(dyns)), jnp.asarray(np.stack(leaks)),
             jnp.asarray(np.stack(refs)), jnp.asarray(np.stack(masks)), Fb,
             jnp.stack(caps), interval_dt, theta, fb=fb, die_n=grid_n,
             n_die=spec.n_die_layers, steps_per_interval=steps_per_interval,
-            n_cg=n_cg, margin=margin, use_pallas=use_pallas, solver=solver,
-            n_mg=n_mg)
+            inner=inner, margin=margin)
     base_ref = dram.DRAMFloorplan(die_w_mm=1.0).base_refresh_W() \
         * len(spec.dram_layers)
     with obs.span("feedback/reports", cases=len(labels)):
@@ -655,11 +633,11 @@ def replay_cases(cases, spec: StackSpec, fb: FeedbackParams, grid_n: int,
 def run_stack_cosim(workloads=("dmm", "fft", "bs"), n_dram: int = 2,
                     grid_n: int = 16, n_intervals: int = 32,
                     t_end: float = 0.25, steps_per_interval: int = 2,
-                    n_cg: int = 40, theta: float = 1.0,
+                    inner: InnerSolve = InnerSolve("pcg", 40),
+                    theta: float = 1.0,
                     fb: FeedbackParams = FeedbackParams(),
                     params: StackParams = PAPER_STACK,
-                    use_pallas: bool = False, solver: str = "pcg",
-                    n_mg: int = 3, n_shards: int | None = None) -> dict:
+                    n_shards: int | None = None) -> dict:
     """The paper's abstract claim, quantified: for each workload replay the
     AP and the same-performance SIMD under ``n_dram`` stacked DRAM dies
     with closed-loop refresh/leakage/DTM feedback, in ONE vmapped batch.
@@ -686,8 +664,7 @@ def run_stack_cosim(workloads=("dmm", "fft", "bs"), n_dram: int = 2,
     reports = replay_cases(cases, spec, fb, grid_n, interval_dt,
                            theta=theta,
                            steps_per_interval=steps_per_interval,
-                           n_cg=n_cg, margin=margin, use_pallas=use_pallas,
-                           solver=solver, n_mg=n_mg, n_shards=n_shards)
+                           inner=inner, margin=margin, n_shards=n_shards)
     out: dict = {"design_points": dps, "spec": spec,
                  "interval_s": interval_dt, "t_end": t_end, "fb": fb}
     for label, rep in reports.items():

@@ -25,6 +25,7 @@ from repro import obs
 from repro.core import cosim
 from repro.core import models as M
 from repro.core.constants import DRAM_LIMIT_C
+from repro.core.thermal import InnerSolve
 from repro import policy as policy_registry
 from repro.stack import feedback
 from repro.stack.spec import PAPER_STACK, StackParams, dram_on_logic
@@ -142,6 +143,8 @@ def _run_group(spec: SweepSpec, points: list[SweepPoint], n_dram: int,
     batch, optionally partitioned over local devices (``n_shards``)."""
     stack_spec = dram_on_logic(n_dram, params)
     fb = resolve_fb(fb_mode, spec.n_picard, policy)
+    inner = InnerSolve(spec.solver,
+                       spec.n_mg if spec.solver == "mg" else spec.n_cg)
     margin = spec.grid_n // 4
     interval_dt = spec.t_end / spec.n_intervals
 
@@ -165,8 +168,7 @@ def _run_group(spec: SweepSpec, points: list[SweepPoint], n_dram: int,
     reports = feedback.replay_cases(
         cases, stack_spec, fb, spec.grid_n, interval_dt,
         theta=spec.theta, steps_per_interval=spec.steps_per_interval,
-        n_cg=spec.n_cg, margin=margin, solver=spec.solver,
-        n_mg=spec.n_mg, n_shards=n_shards)
+        inner=inner, margin=margin, n_shards=n_shards)
     return {(p, mc): SweepRecord(point=p, machine=mc,
                                  report=reports[f"{p.label}/{mc}"])
             for p, mc in keys}

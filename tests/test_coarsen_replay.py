@@ -53,8 +53,8 @@ def _replay(spec, grid, frames, fb, *, steps, dt_scale=None):
         jnp.asarray(dyn), jnp.asarray(l0), jnp.asarray(r0),
         jnp.asarray(lm), grid.fields(), grid.capacity_field(), DT,
         fb=fb, die_n=GRID_N, n_die=spec.n_die_layers,
-        steps_per_interval=steps, n_cg=25, margin=MARGIN,
-        dt_scale=dt_scale)
+        steps_per_interval=steps, inner=thermal.InnerSolve("pcg", 25),
+        margin=MARGIN, dt_scale=dt_scale)
 
 
 def _coarse_vs_exact(spec, act, tol, fb):
@@ -143,5 +143,6 @@ def test_variable_dt_rejects_multigrid():
             jnp.asarray(dyn), jnp.asarray(l0), jnp.asarray(r0),
             jnp.asarray(lm), grid.fields(), grid.capacity_field(), DT,
             fb=feedback.FeedbackParams(), die_n=GRID_N,
-            n_die=spec.n_die_layers, steps_per_interval=1, n_cg=10,
-            margin=MARGIN, solver="mg", dt_scale=jnp.ones(T_BASE))
+            n_die=spec.n_die_layers, steps_per_interval=1,
+            inner=thermal.InnerSolve("mg", 3), margin=MARGIN,
+            dt_scale=jnp.ones(T_BASE))

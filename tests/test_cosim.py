@@ -1,9 +1,12 @@
 """Co-simulation engine (core/cosim.py + thermal implicit steppers):
 implicit-vs-explicit transient agreement, trace-binning energy
 conservation, frame synthesis, the vmapped batch driver, and reports."""
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import cosim, thermal
@@ -51,12 +54,47 @@ def test_transient_implicit_fields_reaches_steady_state():
     T0 = jnp.full(p_dom.shape, thermal.AMBIENT_C, jnp.float32)
     T, peaks = thermal.transient_implicit_fields(
         T0, p_dom, grid.fields(), grid.capacity_field(), dt=0.05,
-        n_steps=60, n_cg=60)
+        n_steps=60, inner=thermal.InnerSolve("pcg", 60))
     T_ss = np.asarray(thermal.steady_state(power, grid))
     die = np.asarray(T)[:4, 3:15, 3:15]
     np.testing.assert_allclose(die, T_ss, atol=0.05)
     assert peaks.shape == (60,)
     assert float(peaks[0]) == pytest.approx(thermal.AMBIENT_C)  # pre-step
+
+
+@pytest.mark.parametrize("theta", [1.0, 0.5], ids=["euler", "cn"])
+@pytest.mark.parametrize("margin", [0, 2])
+def test_implicit_lhs_solver_traced_dt_matches_fixed(theta, margin):
+    """PCG ``make(dt)`` at a dt traced inside a scan body (the
+    variable-dt replay) is the fixed-dt solve made once outside it, bit
+    for bit."""
+    grid = thermal.Grid(die_w=2.3e-3, ny=8, nx=8, margin=margin)
+    cap3 = grid.capacity_field()
+    rng = np.random.default_rng(4)
+    rhs = jnp.asarray(rng.uniform(0, 1e-3, (3,) + cap3.shape), jnp.float32)
+
+    @partial(jax.jit, static_argnames="traced")
+    def steps(F, cap3, rhs, dt, traced):
+        make = thermal.implicit_lhs_solver(thermal.fields_operator(F), F,
+                                           cap3, theta,
+                                           thermal.InnerSolve("pcg", 20))
+        fixed = make(dt)
+
+        def body(_, xs):
+            r, scale = xs
+            return None, (make(dt * scale) if traced else fixed)(r)
+        return jax.lax.scan(body, None, (rhs, jnp.ones(rhs.shape[0])))[1]
+
+    args = (grid.fields(), cap3, rhs, 1e-3)
+    np.testing.assert_array_equal(np.asarray(steps(*args, traced=True)),
+                                  np.asarray(steps(*args, traced=False)))
+
+
+@pytest.mark.parametrize("kind,iters", [("bogus", 3), ("mgcg", 3),
+                                        ("pcg", 0), ("mg", -1)])
+def test_inner_solve_rejects_bad_settings(kind, iters):
+    with pytest.raises(ValueError, match="unknown inner solve|iters"):
+        thermal.InnerSolve(kind, iters)
 
 
 def test_constant_trace_replay_reaches_steady_state():
@@ -70,8 +108,8 @@ def test_constant_trace_replay_reaches_steady_state():
     frames = cosim.power_frames(trace, pmap, float(pmap.sum()) * 0.3, grid)
     T_end, peaks, mins = cosim.cosim_transient(
         jnp.asarray(frames), grid.fields(), grid.capacity_field(),
-        2.0 / 30, steps_per_interval=4, n_cg=60, margin=margin,
-        die_n=grid_n)
+        2.0 / 30, steps_per_interval=4, inner=thermal.InnerSolve("pcg", 60),
+        margin=margin, die_n=grid_n)
     power = np.broadcast_to(pmap, (4, grid_n, grid_n)).astype(np.float32)
     T_ss = np.asarray(thermal.steady_state(power, grid))
     for l in range(4):
@@ -135,7 +173,8 @@ def test_power_frames_conserve_power():
 # --------------------------------------------------------- batched driver
 def test_vmapped_cosim_shapes_and_dtypes():
     res = cosim.run_cosim(workloads=("dmm",), grid_n=8, n_intervals=8,
-                          t_end=0.1, steps_per_interval=1, n_cg=25)
+                          t_end=0.1, steps_per_interval=1,
+                          inner=thermal.InnerSolve("pcg", 25))
     for machine in ("ap", "simd"):
         r = res["dmm"][machine]
         assert r.peak_C.shape == (8, 4)
@@ -158,9 +197,11 @@ def test_cosim_pallas_route_matches_jnp():
     trace = cosim.PowerTrace(act / act.mean())
     frames = jnp.asarray(cosim.power_frames(trace, pmap, 0.0, grid))
     args = (frames, grid.fields(), grid.capacity_field(), 0.02)
-    kw = dict(steps_per_interval=2, n_cg=30, margin=margin, die_n=grid_n)
-    _, pk_j, mn_j = cosim.cosim_transient(*args, **kw)
-    _, pk_p, mn_p = cosim.cosim_transient(*args, **kw, use_pallas=True)
+    kw = dict(steps_per_interval=2, margin=margin, die_n=grid_n)
+    _, pk_j, mn_j = cosim.cosim_transient(
+        *args, **kw, inner=thermal.InnerSolve("pcg", 30))
+    _, pk_p, mn_p = cosim.cosim_transient(
+        *args, **kw, inner=thermal.InnerSolve("pcg", 30, pallas=True))
     np.testing.assert_allclose(np.asarray(pk_j), np.asarray(pk_p),
                                rtol=1e-5, atol=1e-3)
     np.testing.assert_allclose(np.asarray(mn_j), np.asarray(mn_p),

@@ -276,7 +276,7 @@ def _sort_ap_case(spec):
 def _replay(case, spec, fb):
     return feedback.replay_cases(
         case, spec, fb, _GRID_N, 0.25 / _N_INT, steps_per_interval=1,
-        n_cg=25, margin=_GRID_N // 4)["sort/ap"]
+        inner=thermal.InnerSolve("pcg", 25), margin=_GRID_N // 4)["sort/ap"]
 
 
 def test_no_spec_traces_no_random_ops():
@@ -288,7 +288,8 @@ def test_no_spec_traces_no_random_ops():
     _, leaves = case[0]
     dyn, l0, r0, lm, F, cap3 = leaves
     kw = dict(die_n=_GRID_N, n_die=spec.n_die_layers,
-              steps_per_interval=1, n_cg=5, margin=_GRID_N // 4)
+              steps_per_interval=1, inner=thermal.InnerSolve("pcg", 5),
+              margin=_GRID_N // 4)
     clean = str(jax.make_jaxpr(
         lambda *a: feedback.closed_loop_replay(
             *a, 0.02, fb=feedback.FeedbackParams(), **kw))(
@@ -459,7 +460,7 @@ import jax, numpy as np
 assert len(jax.devices()) == 4, jax.devices()
 from repro.faults import SensorFaultSpec
 from repro.policy import PerDiePolicy
-from repro.core import cosim
+from repro.core import cosim, thermal
 from repro.stack import feedback
 from repro.stack.spec import PAPER_STACK, dram_on_logic
 
@@ -473,8 +474,9 @@ fb = feedback.FeedbackParams(
     faults=SensorFaultSpec(seed=3, n_sensors=3, noise_C=0.8,
                            n_stuck=1, p_dropout=0.1))
 runs = {n: feedback.replay_cases(case, spec, fb, 8, 0.02,
-                                steps_per_interval=1, n_cg=15, margin=2,
-                                n_shards=n)["sort/ap"]
+                                steps_per_interval=1,
+                                inner=thermal.InnerSolve("pcg", 15),
+                                margin=2, n_shards=n)["sort/ap"]
         for n in (None, 1, 3, 4)}
 # device-count invariance: every sharded run is bitwise the 1-shard run
 ref = runs[1]

@@ -83,11 +83,10 @@ def test_transient_implicit_mg_matches_pcg():
     """The fixed-cycle MG inner solve reproduces the PCG transient."""
     grid = thermal.Grid(die_w=5e-3, ny=16, nx=16, spec=dram_on_logic(2))
     p = _logic_power(grid)
-    T1, pk1 = thermal.transient_solve_implicit(p, grid, t_end=0.2,
-                                               n_steps=32, n_cg=80)
-    T2, pk2 = thermal.transient_solve_implicit(p, grid, t_end=0.2,
-                                               n_steps=32, solver="mg",
-                                               n_mg=3)
+    T1, pk1 = thermal.transient_solve_implicit(
+        p, grid, t_end=0.2, n_steps=32, inner=thermal.InnerSolve("pcg", 80))
+    T2, pk2 = thermal.transient_solve_implicit(
+        p, grid, t_end=0.2, n_steps=32, inner=thermal.InnerSolve("mg", 3))
     assert float(jnp.abs(T1 - T2).max()) < 0.1
     assert float(jnp.abs(pk1 - pk2).max()) < 0.1
 

@@ -270,12 +270,12 @@ def _lower_transient_fields():
     T0 = jnp.zeros((grid.n_layers, grid.dom_ny, grid.dom_nx), jnp.float32)
     return thermal.transient_implicit_fields.lower(
         T0, T0, grid.fields(), grid.capacity_field(), 1e-3, 3,
-        solver="mg").as_text()
+        inner=thermal.InnerSolve("mg", 3)).as_text()
 
 
 def _lower_closed_loop():
     import jax.numpy as jnp
-    from repro.core import cosim
+    from repro.core import cosim, thermal
     from repro.core import models as M
     from repro.stack import feedback
     from repro.stack.spec import PAPER_STACK, dram_on_logic
@@ -288,7 +288,8 @@ def _lower_closed_loop():
     Fb = {k: v[None] for k, v in F.items()}
     return feedback.closed_loop_batch.lower(
         *batch, Fb, cap3[None], 1e-3, fb=feedback.FeedbackParams(),
-        die_n=8, n_die=spec.n_die_layers, margin=2, n_cg=5).as_text()
+        die_n=8, n_die=spec.n_die_layers, margin=2,
+        inner=thermal.InnerSolve("pcg", 5)).as_text()
 
 
 @pytest.mark.parametrize("lower", [_lower_transient_fields,

@@ -42,8 +42,8 @@ def _legacy_closed_loop(dyn_frames, leak0, refresh0, logic_mask, F, cap3,
     A = lambda v: thermal.apply_operator_fields(v, F)
     if dt_scale is None:
         dt = interval_dt / steps_per_interval
-        solve = thermal.implicit_lhs_solver(A, F, cap3, dt, theta,
-                                            solver="pcg", n_cg=n_cg)
+        solve = thermal.implicit_lhs_solver(
+            A, F, cap3, theta, thermal.InnerSolve("pcg", n_cg))(dt)
         solve_for = lambda _scale: solve
     else:
         diagA = thermal._diag_fields(F)
@@ -128,7 +128,8 @@ def _replay(spec, grid, frames, fb, dt_scale=None, **kw):
     return feedback.closed_loop_replay(
         *frames, grid.fields(), grid.capacity_field(), DT, fb=fb,
         die_n=GRID_N, n_die=spec.n_die_layers, steps_per_interval=1,
-        n_cg=20, margin=MARGIN, dt_scale=dt_scale, **kw)
+        inner=thermal.InnerSolve("pcg", 20), margin=MARGIN,
+        dt_scale=dt_scale, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -206,7 +206,8 @@ def test_disabled_feedback_matches_cosim_within_tenth_degree():
     grid_n, margin, n_int = 8, 2, 10
     dp, fp, pmap, trace, spec, grid = _open_loop_case(grid_n, margin, n_int)
     interval_dt = 0.25 / n_int
-    kw = dict(steps_per_interval=2, n_cg=40, margin=margin, die_n=grid_n)
+    kw = dict(steps_per_interval=2, inner=thermal.InnerSolve("pcg", 40),
+              margin=margin, die_n=grid_n)
     frames = cosim.power_frames(trace, pmap, fp.leakage_W(), grid)
     _, pk_ref, mn_ref = cosim.cosim_transient(
         jnp.asarray(frames), grid.fields(), grid.capacity_field(),
@@ -233,12 +234,13 @@ def test_closed_loop_converges_and_feedback_heats():
     """Picard residual meets the documented bar; refresh/leakage feedback
     strictly raises the hot die's temperature on the hot (SIMD) stack."""
     fb = feedback.FeedbackParams(dtm_trip_C=math.inf)   # isolate heating
+    inner = thermal.InnerSolve("pcg", 30)
     res = feedback.run_stack_cosim(
         workloads=("dmm",), n_dram=1, grid_n=8, n_intervals=12,
-        t_end=0.25, steps_per_interval=1, n_cg=30, fb=fb)
+        t_end=0.25, steps_per_interval=1, inner=inner, fb=fb)
     res0 = feedback.run_stack_cosim(
         workloads=("dmm",), n_dram=1, grid_n=8, n_intervals=12,
-        t_end=0.25, steps_per_interval=1, n_cg=30,
+        t_end=0.25, steps_per_interval=1, inner=inner,
         fb=feedback.FeedbackParams.disabled())
     for machine in ("ap", "simd"):
         r = res["dmm"][machine]
@@ -260,7 +262,8 @@ def test_dtm_throttle_caps_and_costs_runtime():
                                      dtm_floor=0.3)
     run = lambda fb: feedback.run_stack_cosim(
         workloads=("dmm",), n_dram=1, grid_n=8, n_intervals=12,
-        t_end=0.25, steps_per_interval=1, n_cg=30, fb=fb)["dmm"]["ap"]
+        t_end=0.25, steps_per_interval=1,
+        inner=thermal.InnerSolve("pcg", 30), fb=fb)["dmm"]["ap"]
     r_dtm = run(fb_hot)
     r_free = run(feedback.FeedbackParams(dtm_trip_C=math.inf))
     assert r_dtm.dtm_slowdown > 1.05
@@ -272,7 +275,8 @@ def test_dtm_throttle_caps_and_costs_runtime():
 def test_run_stack_cosim_batch_shapes_and_ordering():
     res = feedback.run_stack_cosim(
         workloads=("dmm", "fft"), n_dram=2, grid_n=8, n_intervals=8,
-        t_end=0.1, steps_per_interval=1, n_cg=25)
+        t_end=0.1, steps_per_interval=1,
+        inner=thermal.InnerSolve("pcg", 25))
     spec = res["spec"]
     assert spec.n_die_layers == 6
     for w in ("dmm", "fft"):
