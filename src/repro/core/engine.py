@@ -24,6 +24,15 @@ Bookkeeping (exact, not statistical):
   This generalizes eq (16): with the adder's 1/8 match probability the
   expectation of our measured count equals the paper's closed form — tested in
   tests/test_paper_models.py.
+
+Cycle counters update at once.  A schedule's matched counts stay on the
+device: ``run`` logs them, and the energy, event and trace accounting
+that needs them (with every accounting step queued behind them, so the
+float64 sums keep their order) is replayed on the host when the
+accounting is next read — ``counters()``, ``energy``, ``events``,
+``trace_events()``, ``power_trace()``, ``energy_uJ()`` — or extended
+eagerly, or when ``read`` fetches a field, whose transfer carries them.
+So a program of many schedules crosses to the host once, at its result.
 """
 from __future__ import annotations
 
@@ -142,7 +151,8 @@ class PassSchedule:
 # (workloads/_device.py) thread an APState through lax.scan / lax.while_loop
 # bodies so entire data-dependent inner loops run as ONE compiled program —
 # per-pass matched counts ride along as scan outputs and cross to the host
-# exactly once per workload phase.
+# exactly once per workload phase (``APEngine.run``'s counts cross once per
+# program: at its ``read``, or at the first accounting read).
 # ---------------------------------------------------------------------------
 
 #: APState.counters layout (int32): on-device totals mirroring the host
@@ -344,18 +354,33 @@ class APEngine:
         self.write_cycles = 0
         self.bwrite_cycles = 0
         self.read_cycles = 0
-        self.energy = 0.0             # normalized (SRAM write = 1)
-        self.events = {"match": 0, "mismatch": 0, "write": 0, "miswrite": 0}
+        self._energy = 0.0            # normalized (SRAM write = 1)
+        self._events = {"match": 0, "mismatch": 0, "write": 0, "miswrite": 0}
         # power trace: per accounted event, the cycle it completed on and its
         # energy (exact same accounting as `energy` — binned by cosim.py)
         self._trace_cycles: list = []     # ints or int64 arrays
         self._trace_energy: list = []     # floats or float64 arrays
+        # deferred accounting, replayed in order by _flush: (cycle, sched,
+        # matched) per run, (cycle, None, k) per broadcast write
+        self._pending: list = []
+
+    @property
+    def energy(self) -> float:
+        """Accounted energy, normalized (SRAM write = 1)."""
+        self._flush()
+        return self._energy
+
+    @property
+    def events(self) -> dict:
+        """Accounted match / mismatch / write / miswrite bit events."""
+        self._flush()
+        return self._events
 
     def counters(self) -> dict:
         out = dict(cycles=self.cycles, compare_cycles=self.compare_cycles,
                    write_cycles=self.write_cycles, bwrite_cycles=self.bwrite_cycles,
                    read_cycles=self.read_cycles, energy=self.energy)
-        out.update(self.events)
+        out.update(self._events)
         return out
 
     # ------------------------------------------------------------- data I/O
@@ -377,9 +402,8 @@ class APEngine:
         """Host-side readback of a field for all words (charges n read cycles)."""
         with obs.span("engine/read", width=field.width):
             self.charge_read(self.n_words)
-            halves = bp.field_words(self.planes, field.start, field.width)
-            with obs.span("sync/read"):
-                halves = jax.device_get(halves)
+            halves = self._flush(bp.field_words(self.planes, field.start,
+                                                field.width), "sync/read")
             with obs.span("engine/unpack"):
                 vals = bp.widen_words(*halves)
         if signed and field.width < 64:
@@ -435,27 +459,32 @@ class APEngine:
         self.cycles += 1
         self.bwrite_cycles += 1
         if self.collect_stats:
-            self._account_write(len(cols), self.n_words)
+            self._pending.append((self.cycles, None, len(cols)))
 
     # ----------------------------------------- accounting without executing
     # Device-resident programs compute per-pass matched counts on device,
     # transfer them ONCE per workload phase, and replay them through these
     # chargers — producing cycle/energy/event/trace accounting bit-identical
     # to the eager per-cycle path (tests/test_device_workloads.py).
+    # ``charge_run`` defers: it logs the counts where they are (on device
+    # after ``run``) and ``_flush`` fetches every logged array in one
+    # transfer when the accounting is read or extended eagerly.
 
     def charge_compare(self, k: int, matched: int) -> None:
         """Account one COMPARE cycle (k active columns, matched rows)."""
+        self._flush()
         self.cycles += 1
         self.compare_cycles += 1
         if self.collect_stats:
-            self._account_compare(int(k), int(matched))
+            self._account_compare(int(k), int(matched), self.cycles)
 
     def charge_write(self, k: int, matched: int) -> None:
         """Account one tagged-WRITE cycle (k active columns, matched rows)."""
+        self._flush()
         self.cycles += 1
         self.write_cycles += 1
         if self.collect_stats:
-            self._account_write(int(k), int(matched))
+            self._account_write(int(k), int(matched), self.cycles)
 
     def charge_read(self, n_rows: int) -> None:
         """Account ``n_rows`` sequential read cycles (1 cycle/row, §2.1)."""
@@ -463,32 +492,45 @@ class APEngine:
         self.cycles += int(n_rows)
 
     def charge_run(self, sched: PassSchedule, matched) -> None:
-        """Account a full pass schedule from its per-pass matched counts."""
-        with obs.span("engine/charge", passes=sched.n_passes):
-            P = sched.n_passes
-            self.cycles += 2 * P           # each pass = compare + write
-            self.compare_cycles += P
-            self.write_cycles += P
-            if self.collect_stats:
-                if isinstance(matched, jax.Array):
-                    with obs.span("sync/matched"):
-                        matched = np.asarray(matched)
-                m = np.asarray(matched, np.int64)
-                n = self.n_words
-                kc = sched.kc.astype(np.float64)
-                kw = sched.kw.astype(np.float64)
-                mf = m.astype(np.float64)
-                pw = self.power
-                e_pass = kc * (pw.p_m * mf + pw.p_mm * (n - mf)) \
-                    + kw * (pw.p_w * mf + pw.p_mw * (n - mf))
-                self.energy += float(e_pass.sum())
-                self._trace_cycles.append(
-                    self.cycles - 2 * P + 2 * np.arange(1, P + 1, dtype=np.int64))
-                self._trace_energy.append(e_pass)
-                self.events["match"] += int(m.sum())
-                self.events["mismatch"] += int(P) * n - int(m.sum())
-                self.events["write"] += int((kw * mf).sum())
-                self.events["miswrite"] += int((kw * (n - mf)).sum())
+        """Account a full pass schedule from its per-pass matched counts.
+
+        ``matched`` (host or device) holds at least ``n_passes`` counts;
+        a bucketed run's padded tail is ignored.  Cycles count at once;
+        the rest waits in the log for :meth:`_flush`.
+        """
+        P = sched.n_passes
+        self.cycles += 2 * P           # each pass = compare + write
+        self.compare_cycles += P
+        self.write_cycles += P
+        if self.collect_stats:
+            obs.count("engine/matched/deferred")
+            self._pending.append((self.cycles, sched, matched))
+
+    def _flush(self, also=None, sync: str = "sync/matched"):
+        """Replay the deferred accounting log, in order.
+
+        Every logged device array crosses in ONE ``jax.device_get``,
+        which also carries ``also`` (a pytree of device arrays, returned
+        on the host) so ``read`` ends a program with one transfer.
+        """
+        if not self._pending and also is None:
+            return None
+        pending, self._pending = self._pending, []
+        counts = [m for _, sched, m in pending if sched is not None]
+        on_device = any(isinstance(m, jax.Array) for m in counts)
+        with obs.span("engine/charge", schedules=len(counts)):
+            if on_device or also is not None:
+                if on_device:
+                    obs.count("engine/matched/fetch")
+                with obs.span(sync):
+                    counts, also = jax.device_get((counts, also))
+            counts = iter(counts)
+            for cycle, sched, k in pending:
+                if sched is None:
+                    self._account_write(k, self.n_words, cycle)
+                else:
+                    self._account_run(sched, next(counts), cycle)
+        return also
 
     def charge_bulk(self, *, cycles: int = 0, compare_cycles: int = 0,
                     write_cycles: int = 0, read_cycles: int = 0,
@@ -514,6 +556,7 @@ class APEngine:
           ONE trace chunk, which concatenates to the same flat arrays.
         * counter/event deltas are exact ints.
         """
+        self._flush()
         self.cycles += int(cycles)
         self.compare_cycles += int(compare_cycles)
         self.write_cycles += int(write_cycles)
@@ -521,15 +564,15 @@ class APEngine:
         if not self.collect_stats:
             return
         if energy_terms is not None and len(energy_terms):
-            self.energy = float(np.cumsum(np.concatenate(
-                [[self.energy], np.asarray(energy_terms, np.float64)]))[-1])
+            self._energy = float(np.cumsum(np.concatenate(
+                [[self._energy], np.asarray(energy_terms, np.float64)]))[-1])
         if trace_cycles is not None and len(trace_cycles):
             self._trace_cycles.append(np.asarray(trace_cycles, np.int64))
             self._trace_energy.append(np.asarray(trace_energy, np.float64))
-        self.events["match"] += int(match)
-        self.events["mismatch"] += int(mismatch)
-        self.events["write"] += int(write)
-        self.events["miswrite"] += int(miswrite)
+        self._events["match"] += int(match)
+        self._events["mismatch"] += int(mismatch)
+        self._events["write"] += int(write)
+        self._events["miswrite"] += int(miswrite)
 
     def clear(self, field: Field) -> None:
         self.bwrite(field.cols(), [0] * field.width)
@@ -574,7 +617,6 @@ class APEngine:
                 self.planes, matched = _run_schedule(
                     self.planes, jnp.asarray(cc), jnp.asarray(ck),
                     jnp.asarray(wc), jnp.asarray(wk))
-            matched = matched[:P]
         self.charge_run(sched, matched)
 
     # -------------------------------------------------- functional bridge
@@ -595,25 +637,46 @@ class APEngine:
         self.tag = state.tag
 
     # ------------------------------------------------------ energy helpers
-    def _account_compare(self, k: int, matched: int) -> None:
+    # ``cycle`` is the cycle the event completed on (the trace's x).
+    def _account_compare(self, k: int, matched: int, cycle: int) -> None:
         n = self.n_words
         pw = self.power
         e = k * (pw.p_m * matched + pw.p_mm * (n - matched))
-        self.energy += e
-        self._trace_cycles.append(self.cycles)
+        self._energy += e
+        self._trace_cycles.append(cycle)
         self._trace_energy.append(e)
-        self.events["match"] += matched
-        self.events["mismatch"] += n - matched
+        self._events["match"] += matched
+        self._events["mismatch"] += n - matched
 
-    def _account_write(self, k: int, matched: int) -> None:
+    def _account_write(self, k: int, matched: int, cycle: int) -> None:
         n = self.n_words
         pw = self.power
         e = k * (pw.p_w * matched + pw.p_mw * (n - matched))
-        self.energy += e
-        self._trace_cycles.append(self.cycles)
+        self._energy += e
+        self._trace_cycles.append(cycle)
         self._trace_energy.append(e)
-        self.events["write"] += k * matched
-        self.events["miswrite"] += k * (n - matched)
+        self._events["write"] += k * matched
+        self._events["miswrite"] += k * (n - matched)
+
+    def _account_run(self, sched: PassSchedule, matched, cycle: int) -> None:
+        """A schedule's passes, ending on ``cycle``, from host counts."""
+        P = sched.n_passes
+        m = np.asarray(matched, np.int64)[:P]
+        n = self.n_words
+        kc = sched.kc.astype(np.float64)
+        kw = sched.kw.astype(np.float64)
+        mf = m.astype(np.float64)
+        pw = self.power
+        e_pass = kc * (pw.p_m * mf + pw.p_mm * (n - mf)) \
+            + kw * (pw.p_w * mf + pw.p_mw * (n - mf))
+        self._energy += float(e_pass.sum())
+        self._trace_cycles.append(
+            cycle - 2 * P + 2 * np.arange(1, P + 1, dtype=np.int64))
+        self._trace_energy.append(e_pass)
+        self._events["match"] += int(m.sum())
+        self._events["mismatch"] += int(P) * n - int(m.sum())
+        self._events["write"] += int((kw * mf).sum())
+        self._events["miswrite"] += int((kw * (n - mf)).sum())
 
     # ------------------------------------------------------ power trace
     def trace_events(self) -> tuple[np.ndarray, np.ndarray]:
@@ -624,6 +687,7 @@ class APEngine:
         Cycle spans with no events (host loads, sequential reads) simply
         contribute zero-energy intervals when binned.
         """
+        self._flush()
         if not self._trace_cycles:
             return (np.zeros(0, np.int64), np.zeros(0, np.float64))
         cyc = np.concatenate([np.atleast_1d(np.asarray(c, np.int64))
